@@ -1374,6 +1374,9 @@ def main():
     p = argparse.ArgumentParser()
     p.add_argument("--n", type=int, default=None)
     args = p.parse_args()
+    from repro import compile_cache
+
+    compile_cache.configure()
     from benchmarks.common import CsvSink
 
     sink = CsvSink()

@@ -1,8 +1,9 @@
 """Roofline analysis from the dry-run's compiled artifacts (§Roofline).
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16/chip, 819 GB/s HBM/chip,
-~50 GB/s/link ICI. For each (arch × shape × mesh) cell recorded by
-``repro.launch.dryrun`` this derives:
+Hardware constants come from ``PEAKS``, keyed by the ``device_kind`` the
+cell was compiled for; a device without published peaks is an error. For
+each (arch × shape × mesh) cell recorded by ``repro.launch.dryrun`` this
+derives:
 
     compute term    = HLO_FLOPs(dev)        / peak_FLOPs
     memory term     = HLO_bytes(dev)        / HBM_bw
@@ -23,9 +24,21 @@ import time
 
 from benchmarks.common import banner
 
-PEAK_FLOPS = 197e12       # bf16 / chip
-HBM_BW = 819e9            # B/s / chip
-LINK_BW = 50e9            # B/s / link
+# Published per-chip peaks, keyed by jax's ``device_kind``. Source: Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600
+# Gbit/s of interconnect (4 links, 50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind) -> dict:
+    """The peaks of ``device_kind``; an unknown device is an error."""
+    if device_kind not in PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            "to benchmarks/roofline.py PEAKS with their source")
+    return PEAKS[device_kind]
 
 TOKENS = {
     "train_4k": 4096 * 256,
@@ -54,14 +67,15 @@ def analyze_cell(d: dict) -> dict:
     tokens = TOKENS[d["shape"]]
     mult = 6.0 if kind == "train" else 2.0
     model_flops_dev = mult * n_active * tokens / devices
+    peak = peaks(d.get("device_kind"))
 
-    t_c = hlo["flops"] / PEAK_FLOPS
-    t_m = hlo["hbm_bytes"] / HBM_BW
-    t_l = hlo["collective_link_bytes"] / LINK_BW
+    t_c = hlo["flops"] / peak["flops"]
+    t_m = hlo["hbm_bytes"] / peak["hbm_bw"]
+    t_l = hlo["collective_link_bytes"] / peak["link_bw"]
     terms = {"compute": t_c, "memory": t_m, "collective": t_l}
     dom = max(terms, key=terms.get)
     bound = terms[dom]
-    useful_t = model_flops_dev / PEAK_FLOPS
+    useful_t = model_flops_dev / peak["flops"]
     return {
         "arch": d["arch"], "shape": d["shape"], "mesh": d["mesh"],
         "kind": kind,

@@ -59,6 +59,9 @@ def main() -> int:
                         "(BENCH_runtime.json in CI)")
     args = p.parse_args()
 
+    from repro import compile_cache
+
+    compile_cache.configure()
     from benchmarks import common
     if args.reduced or args.smoke:
         common.REDUCED = True

@@ -3,8 +3,10 @@
 Serves the same bursty stream three ways — the numpy columnar oracle,
 ``array_backend="jax_interpret"`` (the bit-parity audit mode), and compiled
 ``array_backend="jax"`` — and verifies the parity contract on the spot:
-interpret mode must match the oracle bit-for-bit on every record column,
-compiled mode must make identical decisions with floats within tolerance.
+every mode must make the oracle's decisions; on float64 (the CPU) interpret
+mode also matches every record column bit for bit; compiled floats agree to
+``FLOAT_RTOL``, which both branches meet — float64 on the CPU, two-float
+(~2**-48 relative) on the TPU, where plain f32 (~6e-8) would not.
 
 Then demonstrates persistent residency: a 3-chunk resident stream places
 every chunk with the CIL pools / surplus bank / edge horizons held
@@ -19,6 +21,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import jax_core
 from repro.core.decision import DecisionEngine, MinLatencyPolicy
 from repro.core.fit import build_fleet_predictor, fit_app
@@ -31,7 +34,9 @@ CONFIGS = (1280, 1536, 1792)
 FLEET = {"edge0": 1.0, "edge1": 1.0, "edge2": 0.6}
 C_MAX = 6e-6            # $/task budget (Alg. 1)
 ALPHA = 0.05
+FLOAT_RTOL = 1e-9       # compiled-vs-oracle float agreement (see docstring)
 
+compile_cache.configure()
 print("fitting IR component models (twin ground truth)...")
 twin, models = fit_app("IR", seed=0, n_inputs=120, configs=CONFIGS)
 tasks = BurstyWorkload(rate_per_s=4.0, size_sampler=twin.sample_input,
@@ -65,16 +70,21 @@ COLS = ("predicted_latency_ms", "predicted_cost", "actual_latency_ms",
         "actual_cost", "allowed_cost", "completion_ms", "queue_wait_ms",
         "exec_ms", "predicted_cold", "actual_cold", "feasible")
 
+float64 = jax_core.platform() != "tpu"
 bit_equal = (list(ref.records.targets) == list(interp.records.targets)
              and all(np.array_equal(getattr(ref.records, c),
                                     getattr(interp.records, c))
                      for c in COLS))
-dec_equal = list(ref.records.targets) == list(comp.records.targets)
+dec_equal = (list(ref.records.targets) == list(comp.records.targets)
+             == list(interp.records.targets))
 close = all(np.allclose(getattr(ref.records, c).astype(float),
-                        getattr(comp.records, c).astype(float), rtol=1e-9)
+                        getattr(comp.records, c).astype(float),
+                        rtol=FLOAT_RTOL)
             for c in COLS)
-assert bit_equal, "interpret mode must be bit-identical to the numpy oracle"
-assert dec_equal and close, "compiled mode must be decision-identical"
+assert dec_equal, "every jax mode must make the oracle's decisions"
+assert bit_equal or not float64, \
+    "float64 interpret mode must be bit-identical to the numpy oracle"
+assert close, f"compiled floats must agree to rtol={FLOAT_RTOL}"
 
 core = jax_core.core_for(eng_jx)
 print(f"\nnumpy oracle          : {t_np:.2f} s")
@@ -116,6 +126,6 @@ print(f"resident stream       : 3/3 chunks device-resident, "
       f"{r['chunk_commits']} chunk commits, prefetched {r['prefetched']}, "
       f"no-retrace continuation: {no_retrace}")
 
-print("\nOn CPU the compiled path loses to numpy (XLA scan overhead); on an "
-      "accelerator\nthe same code is the fast path — see "
-      "benchmarks/bench_runtime.py sections 9 and 11.")
+print("\nTimes above are host wall-clock. No speed of the device path has "
+      "been measured\non a chip yet; on the CPU the compiled path loses to "
+      "numpy (XLA scan overhead).")

@@ -501,10 +501,14 @@ class DecisionEngine:
     ``"numpy"`` (default, the oracle), ``"jax"`` (jit-compiled
     device-resident ``repro.core.jax_core`` — decision-identical, float
     agreement at tolerance), or ``"jax_interpret"`` (op-by-op float64 jax —
-    bit-identical to numpy, the parity-test mode). Anything the jax core
-    cannot replicate (hedged/custom policies, quantile prediction,
-    out-of-order arrivals, ``record_decisions``, custom target/model types)
-    silently takes the numpy path, chunk by chunk.
+    bit-identical to numpy, the parity-test mode). A chunk the jax core
+    cannot replicate on semantic grounds (hedged/custom policies, quantile
+    prediction, out-of-order arrivals, ``record_decisions``, custom
+    target/model types — ``jax_core.CoreIneligible``) takes the numpy path,
+    chunk by chunk, and ``serve_stream`` counts it
+    (``stream_stats["residency"]["fallback_chunks"]``). Anything else that
+    keeps the core from building — JAX missing, a compile error, a device
+    the GBRT kernel cannot run on — raises instead of serving on numpy.
     """
 
     def __init__(self, predictor: Predictor, policy: Policy,

@@ -17,18 +17,19 @@ boundaries stop being host↔device sync points):
    padded to one ``(n_configs, B)`` matrix), or through the
    ``repro.kernels.gbrt_predict`` Pallas kernel on TPU / ``GBRT_KERNEL_MODE
    == "force"``.
-2. **Place** — a chunk-level fixed-point driver replaces the host
-   speculate-and-repair loop: a ``lax.while_loop`` carries the speculated
-   policy-view codes (``-1`` = "no state effects yet", the frozen-state
-   guess), and each iteration replays ALL THREE sequential recurrences from
-   the chunk-start state under the current guess — the surplus bank and FIFO
-   busy horizons as ``lax.scan`` left folds (or max-plus
-   ``lax.associative_scan`` / ``repro.kernels.linear_scan`` forms in
-   ``assoc`` mode, see ``recurrence.maxplus_combine``), the CIL warm/cold
-   event walk as a ``lax.scan`` over fixed-capacity container pools. By the
-   same induction the numpy repair loop relies on, the exact prefix grows by
-   ≥ 1 row per iteration, so the fixed point (``pass(g) == g``) IS the true
-   sequential trajectory and is reached in ≤ R+1 passes (2–3 in practice).
+2. **Place** — a fixed-point driver replaces the host speculate-and-repair
+   loop: a ``lax.while_loop`` carries the speculated policy-view codes
+   (``-1`` = "no state effects yet", the frozen-state guess), and each
+   iteration replays ALL THREE sequential recurrences from the block-start
+   state under the current guess — the surplus bank and FIFO busy horizons
+   as ``lax.scan`` left folds (or max-plus ``lax.associative_scan`` / prefix
+   forms in ``assoc`` mode, see ``recurrence.maxplus_combine``), the CIL
+   warm/cold event walk as a ``lax.scan`` over fixed-capacity container
+   pools. By the same induction the numpy repair loop relies on, the exact
+   prefix grows by ≥ 1 row per iteration, so the fixed point
+   (``pass(g) == g``) IS the true sequential trajectory and is reached in
+   ≤ R+1 passes. A chunk runs as ``PLACE_BLOCK``-row blocks in order (see
+   ``_build_place``): where decisions feed back, a pass repairs few rows.
 3. **Commit or stay resident** — decision outputs are sliced to the chunk on
    host either way. Without stream residency (standalone ``place_many``),
    CIL pools, edge horizons and the surplus bank are written back exactly
@@ -52,6 +53,16 @@ boundaries stop being host↔device sync points):
    blocked multi-config Pallas kernel (``gbrt_predict_multi``) instead of a
    launch per cloud config.
 
+Arithmetic: the numpy oracle decides in float64. On the CPU the core runs
+float64 under ``jax.enable_x64``. The TPU has no float64, so there every
+decision-relevant value is a two-float pair (``repro.kernels.dfloat``, ~48
+bits): predicted latencies and costs, the surplus bank, and times. Times
+are also rebased per chunk: the device holds ``t - base`` with ``base`` the
+chunk's first arrival, kept exactly on the host, so no absolute time of a
+long stream (1e8+ ms) reaches the device. Costs are gathered from per-config
+tables the host computes with the oracle's own float64 formula. Which branch
+runs is decided by ``platform()``, the core's one read of the device.
+
 Parity contract (mirrors the Pallas kernel tests):
 
 - ``array_backend="jax_interpret"`` — float64 op-by-op execution
@@ -60,14 +71,19 @@ Parity contract (mirrors the Pallas kernel tests):
   constant chains, so the compiled path cannot promise last-ULP equality —
   interpret mode is the oracle, exactly like ``interpret=True`` Pallas.
 - ``array_backend="jax"`` — jit-compiled: decision-equality (identical
-  ``target_codes``) with tolerance-level float agreement.
+  ``target_codes``) with tolerance-level float agreement: about 1e-12
+  relative on the CPU, 2**-48 relative (plus the representation of rebased
+  times) on the TPU's two-float branch.
 
 Fallback rules (all BEFORE any balancer/RNG state is consumed, so a fallback
 chunk is indistinguishable from a numpy chunk): hedged/custom policies,
 non-columnar balancers, quantile prediction, ``record_decisions``, custom
 target/model/pricing types, and out-of-order arrivals all take the existing
-numpy path. Chunks are padded to power-of-two rows (pad rows carry code
-``-1`` and no effects) so streaming tails never retrace the jit cache.
+numpy path. These are semantic refusals (``CoreIneligible``); anything else
+that stops the core from building — JAX missing, a compile error, a device
+the kernel cannot run on — raises. Chunks are padded to power-of-two rows
+(pad rows carry code ``-1`` and no effects) so streaming tails never retrace
+the jit cache.
 """
 
 from __future__ import annotations
@@ -80,6 +96,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from repro.core.cil import ContainerInfoList, ContainerRecord
+from repro.kernels import dfloat
 from repro.core.perf_models import NormalModel, RidgeModel, ScaledModel
 from repro.core.predictor import (
     EdgeTarget,
@@ -97,18 +114,21 @@ from repro.core.workload import task_arrays
 # "auto"  — per-backend pick from the bench section 9 measurement (see
 #           ``resolve_scan_mode``).
 SCAN_MODE = "auto"
-# Measured winners for SCAN_MODE="auto" (bench_runtime section 9's
-# assoc-vs-seq timing; backends not listed default to "assoc"). XLA:CPU
-# executes the short sequential scan faster than the log-depth max-plus
-# associative form at serving chunk sizes — and seq is also the bit-exact
-# association, so CPU keeps it. Accelerator backends win with assoc.
+# Winners for SCAN_MODE="auto" (bench_runtime section 9's assoc-vs-seq
+# timing; backends not listed default to "assoc"). XLA:CPU executes the
+# short sequential scan faster than the log-depth max-plus associative form
+# at serving chunk sizes — and seq is also the bit-exact association, so CPU
+# keeps it. The TPU default ("assoc") has not been timed on a chip yet.
 _AUTO_SCAN = {"cpu": "seq"}
-# Route the assoc-mode surplus prefix through the repro.kernels.linear_scan
-# Pallas kernel (f32 — decision-equality contract; exercised by tests/bench).
-SURPLUS_LINEAR_SCAN = False
 
 POOL_MIN_CAP = 8        # starting CIL container-pool capacity (doubles on demand)
 PAD_MIN = 8             # minimum padded chunk rows
+PLACE_BLOCK = 32        # rows per compiled fixed point (``_build_place``)
+# the place step's per-chunk scalars (every other input is per row), and
+# the state it carries from block to block: (seed key, final key)
+_SCALAR_INPUTS = ("c_max", "alpha", "deadline")
+_STATE_OUTPUTS = (("busy0", "busyF"), ("last0", "lastF"), ("cnt0", "cntF"),
+                  ("h0", "h_fin"), ("s0", "s_fin"))
 MAX_BACKENDS = ("numpy", "jax", "jax_interpret")
 
 
@@ -126,25 +146,134 @@ class CoreIneligible(Exception):
     """This engine's policy/targets/models are outside the jax core's replica."""
 
 
-_JAX = None  # cached import probe: () = unavailable, (jax, jnp, lax) = ready
-
-
 def _modules():
-    global _JAX
-    if _JAX is None:
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax import lax
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
 
-            _JAX = (jax, jnp, lax)
-        except Exception:  # pragma: no cover - jax is baked into the image
-            _JAX = ()
-    return _JAX if _JAX else None
+    return jax, jnp, lax
 
 
-def available() -> bool:
-    return _modules() is not None
+def platform() -> str:
+    """The platform the core builds for (``jax.default_backend()``).
+
+    The core's one read of the device: it picks the arithmetic (float64 on
+    the CPU, two-float on the TPU), the scan mode and the GBRT route. Tests
+    steer the TPU branch on a CPU host by monkeypatching this function."""
+    return _modules()[0].default_backend()
+
+
+# ------------------------------------------------------------ arithmetic
+class _F64:
+    """Float64 arithmetic (CPU, under x64): plain arrays, the very operations
+    of the numpy oracle, so interpret mode stays bit-identical."""
+
+    df = False
+    zero, inf, ninf = 0.0, np.inf, -np.inf
+
+    def __init__(self, jnp):
+        self.jnp = jnp
+
+    @staticmethod
+    def add(a, b):
+        return a + b
+
+    @staticmethod
+    def sub(a, b):
+        return a - b
+
+    @staticmethod
+    def mul(a, b):
+        return a * b
+
+    @staticmethod
+    def lt(a, b):
+        return a < b
+
+    @staticmethod
+    def le(a, b):
+        return a <= b
+
+    @staticmethod
+    def eq(a, b):
+        return a == b
+
+    def maximum(self, a, b):
+        return self.jnp.maximum(a, b)
+
+    def where(self, c, a, b):
+        return self.jnp.where(c, a, b)
+
+    @staticmethod
+    def reduce_min(x, axis):
+        return x.min(axis=axis)
+
+    def argmin(self, x, axis):
+        return self.jnp.argmin(x, axis=axis)
+
+    def argmax(self, x, axis):
+        return self.jnp.argmax(x, axis=axis)
+
+    def cumsum(self, x):
+        return self.jnp.cumsum(x)
+
+    def full(self, shape, v):
+        return self.jnp.full(shape, v, self.jnp.float64)
+
+    @staticmethod
+    def const(v):
+        return float(v)
+
+    def dev(self, x, base: float = 0.0):
+        return self.jnp.asarray(np.asarray(x, np.float64))
+
+    @staticmethod
+    def host(v) -> np.ndarray:
+        return np.asarray(v, np.float64)
+
+
+class _DF32:
+    """Two-float arithmetic (TPU): values are ``(hi, lo)`` f32 pairs; see
+    ``repro.kernels.dfloat``. ``dev`` rebases times by ``base`` on the host
+    in float64 before the split."""
+
+    df = True
+    zero, inf, ninf = (0.0, 0.0), (np.inf, 0.0), (-np.inf, 0.0)
+    add = staticmethod(dfloat.add)
+    sub = staticmethod(dfloat.sub)
+    mul = staticmethod(dfloat.mul)
+    lt = staticmethod(dfloat.lt)
+    le = staticmethod(dfloat.le)
+    eq = staticmethod(dfloat.eq)
+    maximum = staticmethod(dfloat.maximum)
+    where = staticmethod(dfloat.where)
+    reduce_min = staticmethod(dfloat.reduce_min)
+    argmin = staticmethod(dfloat.argmin)
+    argmax = staticmethod(dfloat.argmax)
+
+    def __init__(self, jnp, lax):
+        self.jnp, self.lax = jnp, lax
+
+    def cumsum(self, x):
+        return self.lax.associative_scan(dfloat.add, x)
+
+    def full(self, shape, v):
+        jnp = self.jnp
+        return (jnp.full(shape, v, jnp.float32),
+                jnp.zeros(shape, jnp.float32))
+
+    @staticmethod
+    def const(v):
+        hi, lo = dfloat.split(v)
+        return np.float32(hi), np.float32(lo)
+
+    def dev(self, x, base: float = 0.0):
+        hi, lo = dfloat.split(np.asarray(x, np.float64) - base)
+        return self.jnp.asarray(hi), self.jnp.asarray(lo)
+
+    @staticmethod
+    def host(v) -> np.ndarray:
+        return dfloat.join(*v)
 
 
 def _next_pow2(n: int) -> int:
@@ -154,8 +283,8 @@ def _next_pow2(n: int) -> int:
     return p
 
 
-# Device-resident table/operand hosting, keyed on model identity + scope
-# (x64 flag) with the _CONST1_TABLES weakref idiom — rebuilding a core (e.g.
+# Device-resident table/operand hosting, keyed on model identity + the
+# arithmetic (float64 or two-float) with the _CONST1_TABLES weakref idiom — rebuilding a core (e.g.
 # a hedged-policy swap and back) re-hosts NOTHING, and the per-chunk path
 # does zero host-side operand prep (see ``model_keyed_cache``).
 _DEVICE_TABLES: dict[tuple, dict] = {}
@@ -169,7 +298,8 @@ class DeviceStreamState:
     Holds the sequential placement state ON DEVICE between consecutive
     in-order chunks: fixed-capacity CIL container pools (``busy``/``last``
     at ``cap`` slots per cloud config plus per-config ``cnt``), per-device
-    edge FIFO horizons ``h``, and the Alg. 1 surplus bank ``s``. Host-side
+    edge FIFO horizons ``h``, and the Alg. 1 surplus bank ``s`` (two-float
+    pairs on the TPU branch, where times are relative to ``base``). Host-side
     bookkeeping rides along: ``t_last`` (last committed arrival — validates
     in-order re-entry), ``cnt_max`` (pool-growth bound without materializing
     pools), ``chunks`` (resident chunks absorbed), and ``rng_draws`` (RNG
@@ -185,6 +315,7 @@ class DeviceStreamState:
     h: object = None             # (n_dev,) device array (edge fleets only)
     s: object = None             # scalar device array (MinLatency only)
     cap: int = 0
+    base: float = 0.0            # host time origin of the device times
     t_last: float = -np.inf
     cnt_max: int = 0
     chunks: int = 0
@@ -278,7 +409,7 @@ def _engine_key(engine) -> tuple:
     pred = engine.predictor
     ids = [id(pred), id(engine.policy), type(engine.policy),
            type(engine.balancer), pred.quantile,
-           predictor_mod.GBRT_KERNEL_MODE, SCAN_MODE, SURPLUS_LINEAR_SCAN]
+           predictor_mod.GBRT_KERNEL_MODE, SCAN_MODE, platform()]
     for tgt in pred.cloud_targets:
         ids.append((id(tgt), id(tgt.comp_model), id(tgt.upld_model),
                     id(tgt.start_warm), id(tgt.start_cold),
@@ -299,10 +430,7 @@ class JaxPlacementCore:
     """
 
     def __init__(self, engine):
-        mods = _modules()
-        if mods is None:
-            raise CoreIneligible("jax unavailable")
-        self.jax, self.jnp, self.lax = mods
+        self.jax, self.jnp, self.lax = _modules()
         if not engine._columnar_eligible():
             raise CoreIneligible("engine is not columnar-eligible")
         pred: Predictor = engine.predictor
@@ -328,11 +456,22 @@ class JaxPlacementCore:
         self.lpw = (self.n_dev > 1
                     and type(engine.balancer) is LeastPredictedWaitBalancer)
         mode = predictor_mod.GBRT_KERNEL_MODE
-        tpu = self.jax.default_backend() == "tpu"
-        self.use_gbrt_kernel = mode == "force" or (tpu and mode == "auto")
-        self.dtype = self.jnp.float32 if tpu else self.jnp.float64
-        self._x64 = not tpu
-        self.seq = resolve_scan_mode(self.jax.default_backend()) == "seq"
+        plat = platform()
+        tpu = plat == "tpu"
+        # the TPU has no float64: decide in two-float there (module docstring)
+        self.A = _DF32(self.jnp, self.lax) if tpu else _F64(self.jnp)
+        if self.A.df and any(not c.quantum.is_integer() for c in self.cloud):
+            raise CoreIneligible("two-float billing needs integral quanta")
+        self.use_gbrt_kernel = bool(self.n_cloud) and (
+            mode == "force" or (tpu and mode == "auto"))
+        # interpret mode follows the device the kernel really runs on: off on
+        # a TPU, on for the CPU, an error on any other accelerator
+        self.kernel_interpret = None
+        if self.use_gbrt_kernel:
+            from repro.kernels import interpret_mode
+
+            self.kernel_interpret = interpret_mode()
+        self.seq = resolve_scan_mode(plat) == "seq"
         self.key = _engine_key(engine)
         self._targets = list(pred.cloud_targets) + list(pred.edge_fleet or ())
         self._refs = [weakref.ref(o) for o in (
@@ -359,6 +498,7 @@ class JaxPlacementCore:
             self._finalize = self.jax.jit(self._finalize_fn)
             self._compact = (self.jax.jit(self._build_compact())
                              if self.n_cloud else None)
+            self._shift = self.jax.jit(self._build_shift())
         self.last_stats: dict | None = None
         # ---- stream residency (serve_stream only; see module docstring) ----
         self._resident: DeviceStreamState | None = None
@@ -370,11 +510,9 @@ class JaxPlacementCore:
 
     # ------------------------------------------------------------ lifecycle
     def _scope(self):
-        if not self._x64:
+        if self.A.df:
             return contextlib.nullcontext()
-        from jax.experimental import enable_x64
-
-        return enable_x64()
+        return self.jax.enable_x64(True)
 
     def valid_for(self, engine) -> bool:
         return (self.key == _engine_key(engine)
@@ -387,15 +525,20 @@ class JaxPlacementCore:
                 "state": self._state._cache_size(),
                 "choose": self._choose._cache_size()}
 
+    def _base(self, nows_np) -> float:
+        """Host time origin of one chunk's device times: its first arrival
+        on the two-float branch (times stay small there), 0 on float64."""
+        return float(nows_np[0]) if self.A.df and len(nows_np) else 0.0
+
     # ------------------------------------------------------- device operands
     def _device_tables(self) -> dict:
-        key = (tuple(id(t) for t in self._targets), self._x64)
+        key = (tuple(id(t) for t in self._targets), self.A.df)
         return model_keyed_cache(
             _DEVICE_TABLES, _DEVICE_TABLES_LOCK, key, self._targets,
             self._build_device_tables)
 
     def _build_device_tables(self) -> dict:
-        jnp = self.jnp
+        dev = self.A.dev
         t: dict = {}
         if self.n_cloud:
             bmax = max(1, max(c.breaks.shape[0] for c in self.cloud))
@@ -406,23 +549,40 @@ class JaxPlacementCore:
                 BR[i, :nb] = c.breaks
                 VL[i, :nb + 1] = c.vals
                 VL[i, nb + 1:] = c.vals[-1]
-            t["BR"] = jnp.asarray(BR)
-            t["VL"] = jnp.asarray(VL)
-            t["UP0"] = jnp.asarray(np.array([c.up_theta[0] for c in self.cloud]))
-            t["UP1"] = jnp.asarray(np.array([c.up_theta[1] for c in self.cloud]))
-            t["SW"] = jnp.asarray(np.array([c.start_warm for c in self.cloud]))
-            t["SC"] = jnp.asarray(np.array([c.start_cold for c in self.cloud]))
-            t["ST"] = jnp.asarray(np.array([c.store for c in self.cloud]))
-            t["QNT"] = jnp.asarray(np.array([c.quantum for c in self.cloud]))
-            t["GB"] = jnp.asarray(np.array([c.gb for c in self.cloud]))
-            t["RATE"] = jnp.asarray(np.array([c.rate for c in self.cloud]))
+            t["BR"] = dev(BR)
+            t["VL"] = dev(VL)
+            for k, attr in (("SW", "start_warm"), ("SC", "start_cold"),
+                            ("ST", "store")):
+                t[k] = dev(np.array([getattr(c, attr) for c in self.cloud]))
+            t["UP0"] = dev(np.array([c.up_theta[0] for c in self.cloud]))
+            t["UP1"] = dev(np.array([c.up_theta[1] for c in self.cloud]))
+            if self.A.df:
+                t["QI"], t["COSTK"] = self._cost_tables(VL)
+            else:
+                t["QNT"] = dev(np.array([c.quantum for c in self.cloud]))
+                t["GB"] = dev(np.array([c.gb for c in self.cloud]))
+                t["RATE"] = dev(np.array([c.rate for c in self.cloud]))
         if self.has_edge:
-            t["ET0"] = jnp.asarray(np.array([e.theta[0] for e in self.edges]))
-            t["ET1"] = jnp.asarray(np.array([e.theta[1] for e in self.edges]))
-            t["ESC"] = jnp.asarray(np.array([e.scale for e in self.edges]))
-            t["EIO"] = jnp.asarray(np.array([e.iot for e in self.edges]))
-            t["EST"] = jnp.asarray(np.array([e.store for e in self.edges]))
+            t["ET0"] = dev(np.array([e.theta[0] for e in self.edges]))
+            t["ET1"] = dev(np.array([e.theta[1] for e in self.edges]))
+            t["ESC"] = dev(np.array([e.scale for e in self.edges]))
+            t["EIO"] = dev(np.array([e.iot for e in self.edges]))
+            t["EST"] = dev(np.array([e.store for e in self.edges]))
         return t
+
+    def _cost_tables(self, VL):
+        """Two-float billing: cost by billed-quantum count ``k``, computed
+        on the host with ``LambdaPricing.cost_batch``'s float64 formula —
+        ``((k*q / 1000) * gb) * rate`` — so a cost on the device is the
+        oracle's cost, split. Compute times are bounded by the serving step
+        tables (the GBRT's range), which bounds ``k``."""
+        q = np.array([c.quantum for c in self.cloud])
+        top = np.maximum(np.round(np.abs(VL).max(axis=1)), 1.0)
+        K = int(np.ceil(top / q).max()) + 2
+        k = np.arange(K, dtype=np.float64)
+        cost = np.stack([((k * c.quantum / 1000.0) * c.gb) * c.rate
+                         for c in self.cloud])
+        return self.jnp.asarray(q.astype(np.int32)), self.A.dev(cost)
 
     def _gbrt_kernel_operands(self):
         """Stacked multi-config Pallas operands for the ONE blocked
@@ -431,67 +591,96 @@ class JaxPlacementCore:
         prep)."""
         from repro.kernels.gbrt_predict.ops import multi_kernel_operands
 
-        F, TH, LV, LR, BASE, depth = multi_kernel_operands(
-            self._kernel_models)
-        MEM = self.jnp.asarray(np.array(
-            [[c.memory_mb] for c in self.cloud], np.float32))
-        return F, TH, LV, LR, BASE, MEM, depth
+        return multi_kernel_operands(self._kernel_models,
+                                     [c.memory_mb for c in self.cloud])
 
     # ----------------------------------------------------------- predict jit
     def _build_predict(self):
-        jax, jnp = self.jax, self.jnp
+        jax, jnp, A = self.jax, self.jnp, self.A
         t = self._tables
         nc, nd = self.n_cloud, self.n_dev
         use_kernel = self.use_gbrt_kernel
         kernel_ops = None
-        if use_kernel and nc:
+        if use_kernel:
             from repro.kernels.gbrt_predict.kernel import gbrt_predict_multi
 
-            interpret = jax.default_backend() != "tpu"
-            kernel_ops = self._gbrt_kernel_operands()
+            interpret = self.kernel_interpret
+            *kernel_ops, depth = self._gbrt_kernel_operands()
+
+        def col(x):
+            return jax.tree.map(lambda a: a[:, None], x)
+
+        def row(x):
+            return jax.tree.map(lambda a: a[None, :], x)
+
+        def gbrt_kernel(sizes):
+            # ONE blocked launch over the padded (configs, trees, …) operand
+            # stack — grid (C, row-blocks) — instead of a pallas_call per
+            # cloud config; two-float in and out (see the kernel docstring)
+            if A.df:
+                sh, sl = sizes
+            else:
+                sh = sizes.astype(jnp.float32)
+                sl = (sizes - sh.astype(sizes.dtype)).astype(jnp.float32)
+            ch, cl = gbrt_predict_multi(jnp.stack([sh, sl]), *kernel_ops,
+                                        depth=depth, interpret=interpret)
+            if A.df:
+                return ch.T, cl.T
+            return ch.T.astype(sizes.dtype) + cl.T.astype(sizes.dtype)
+
+        def gbrt_table(sizes):
+            if not A.df:
+                return jax.vmap(
+                    lambda b, v: v[jnp.searchsorted(b, sizes, side="left")]
+                )(t["BR"], t["VL"]).T
+            # searchsorted(side="left") == count of breaks below the size
+            k = A.lt(jax.tree.map(lambda a: a[None], t["BR"]),
+                     jax.tree.map(lambda a: a[:, None, None], sizes)
+                     ).sum(axis=-1)
+            cfg = jnp.arange(nc)[None, :]
+            return jax.tree.map(lambda v: v[cfg, k], t["VL"])
+
+        def billed_cost(compc):
+            if not A.df:
+                billed = jnp.ceil(
+                    jnp.maximum(jnp.round(compc), 1.0) / t["QNT"][None, :]
+                ) * t["QNT"][None, :]
+                return ((billed / 1000.0) * t["GB"][None, :]) \
+                    * t["RATE"][None, :]
+            # np.round, then ceil to the quantum, in exact integers
+            m = jnp.maximum(dfloat.round_half_even(compc), 1.0)
+            q = t["QI"][None, :]
+            k = (m.astype(jnp.int32) + q - 1) // q
+            k = jnp.clip(k, 0, t["COSTK"][0].shape[1] - 1)
+            cfg = jnp.arange(nc)[None, :]
+            return jax.tree.map(lambda v: v[cfg, k], t["COSTK"])
 
         def predict(sizes, nbytes):
             out = {}
             if nc:
-                if use_kernel:
-                    # ONE blocked launch over the padded (n_configs, trees,
-                    # …) operand stack — grid (C, row-blocks) — instead of a
-                    # pallas_call per cloud config. Bit-identical per column
-                    # to the per-config launches (see multi_kernel_operands).
-                    F, TH, LV, LR, BASE, MEM, depth = kernel_ops
-                    x32 = sizes[:, None].astype(jnp.float32)
-                    bn = min(256, x32.shape[0])
-                    comp = gbrt_predict_multi(
-                        x32, MEM, LR, BASE, F, TH, LV, depth=depth,
-                        block_n=bn, interpret=interpret).astype(sizes.dtype)
-                else:
-                    comp = jax.vmap(
-                        lambda b, v: v[jnp.searchsorted(b, sizes, side="left")]
-                    )(t["BR"], t["VL"]).T
-                compc = jnp.maximum(comp, 0.0)
-                upld = jnp.maximum(
-                    t["UP0"][None, :] + nbytes[:, None] * t["UP1"][None, :],
-                    0.0)
+                comp = gbrt_kernel(sizes) if use_kernel else gbrt_table(sizes)
+                compc = A.maximum(comp, A.zero)
+                upld = A.maximum(
+                    A.add(row(t["UP0"]), A.mul(col(nbytes), row(t["UP1"]))),
+                    A.zero)
                 # associate exactly like sum(warm.values()) / occupancy_ms:
                 # ((upld + start) + comp) (+ store)
-                occ_w = (upld + t["SW"][None, :]) + compc
-                occ_c = (upld + t["SC"][None, :]) + compc
-                out["LATW"] = occ_w + t["ST"][None, :]
-                out["LATC"] = occ_c + t["ST"][None, :]
+                occ_w = A.add(A.add(upld, row(t["SW"])), compc)
+                occ_c = A.add(A.add(upld, row(t["SC"])), compc)
+                out["LATW"] = A.add(occ_w, row(t["ST"]))
+                out["LATC"] = A.add(occ_c, row(t["ST"]))
                 out["OCCW"] = occ_w
                 out["OCCC"] = occ_c
                 out["COMPC"] = compc
-                billed = jnp.ceil(
-                    jnp.maximum(jnp.round(compc), 1.0) / t["QNT"][None, :]
-                ) * t["QNT"][None, :]
-                out["COSTC"] = ((billed / 1000.0) * t["GB"][None, :]) \
-                    * t["RATE"][None, :]
+                out["COSTC"] = billed_cost(compc)
             if nd:
-                ec = jnp.maximum(
-                    (t["ET0"][None, :] + sizes[:, None] * t["ET1"][None, :])
-                    * t["ESC"][None, :], 0.0)
+                ec = A.maximum(
+                    A.mul(A.add(row(t["ET0"]),
+                                A.mul(col(sizes), row(t["ET1"]))),
+                          row(t["ESC"])),
+                    A.zero)
                 out["ECOMP"] = ec
-                out["ELAT"] = (ec + t["EIO"][None, :]) + t["EST"][None, :]
+                out["ELAT"] = A.add(A.add(ec, row(t["EIO"])), row(t["EST"]))
             return out
 
         return predict
@@ -508,22 +697,24 @@ class JaxPlacementCore:
     # compares and min-reductions). Compiled mode composes all three inside
     # one jitted ``lax.while_loop`` fixed-point driver under the
     # decision-equality contract; interpret mode hosts the same fixed point
-    # in Python over the jitted pieces and stays bit-exact.
+    # in Python over the jitted pieces and stays bit-exact. Every float goes
+    # through ``self.A`` (plain float64 arrays or two-float pairs); gathers
+    # and slices apply to each array of a value with ``jax.tree.map``.
     def _build_state(self):
-        jax, jnp, lax = self.jax, self.jnp, self.lax
+        jax, jnp, lax, A = self.jax, self.jnp, self.lax, self.A
+        tm = jax.tree.map
         nc, nd, T = self.n_cloud, self.n_dev, self.T
         edge_col, has_edge = self.edge_col, self.has_edge
         is_minlat, lpw, seq = self.is_minlat, self.lpw, self.seq
-        t_idl = self.t_idl
-        surplus_kernel = SURPLUS_LINEAR_SCAN and not seq
+        t_idl = A.const(self.t_idl)
         from repro.core.recurrence import maxplus_combine
 
         def state_fn(guess, P):
             """One full state replay of the chunk under speculated codes
             ``guess`` (policy-view; -1 = no state effects yet — the
             frozen-state guess)."""
-            nows, valid = P["nows"], P["valid"]
-            R = nows.shape[0]
+            nows = P["nows"]
+            R = guess.shape[0]
             rr = jnp.arange(R)
             is_edge_g = (guess == edge_col) if has_edge \
                 else jnp.zeros(R, dtype=bool)
@@ -537,10 +728,13 @@ class JaxPlacementCore:
                     # winner feeds back into the next argmin: sequential only
                     def estep(h, xs):
                         now, ec, ie = xs
-                        w = jnp.maximum(h - now, 0.0)
-                        d = jnp.argmin(w)           # first-min == fleet order
-                        upd = jnp.maximum(h[d], now) + ec[d]
-                        h2 = h.at[d].set(jnp.where(ie, upd, h[d]))
+                        w = A.maximum(A.sub(h, now), A.zero)
+                        d = A.argmin(w, 0)          # first-min == fleet order
+                        hd = tm(lambda a: a[d], h)
+                        upd = A.add(A.maximum(hd, now),
+                                    tm(lambda a: a[d], ec))
+                        h2 = tm(lambda a, u: a.at[d].set(
+                            jnp.where(ie, u, a[d])), h, upd)
                         return h2, (h, d)
 
                     h_fin, (HB, nom) = lax.scan(
@@ -552,55 +746,67 @@ class JaxPlacementCore:
                     if seq:
                         def estep(h, xs):
                             now, ec, pm = xs
-                            return jnp.where(
-                                pm, jnp.maximum(h, now) + ec, h), h
+                            return A.where(
+                                pm, A.add(A.maximum(h, now), ec), h), h
 
                         h_fin, HB = lax.scan(
                             estep, P["h0"], (nows, ECOMP, pushm))
                     else:
                         # exclusive max-plus scan: h_i = max(h0 + A_i, B_i)
-                        a = jnp.where(pushm, ECOMP, 0.0)
-                        b = jnp.where(pushm, nows[:, None] + ECOMP, -jnp.inf)
-                        A, B = lax.associative_scan(
-                            lambda x, y: maxplus_combine(x, y, jnp.maximum),
+                        a = A.where(pushm, ECOMP, A.zero)
+                        b = A.where(pushm, A.add(tm(lambda x: x[:, None],
+                                                    nows), ECOMP), A.ninf)
+                        Ac, Bc = lax.associative_scan(
+                            lambda x, y: maxplus_combine(x, y, A.maximum,
+                                                         A.add),
                             (a, b), axis=0)
-                        z = jnp.zeros((1, nd), a.dtype)
-                        ninf = jnp.full((1, nd), -jnp.inf, b.dtype)
-                        Ax = jnp.concatenate([z, A[:-1]], axis=0)
-                        Bx = jnp.concatenate([ninf, B[:-1]], axis=0)
-                        HB = jnp.maximum(P["h0"][None, :] + Ax, Bx)
-                        h_fin = jnp.maximum(P["h0"] + A[-1], B[-1])
-                waits = jnp.maximum(HB - nows[:, None], 0.0)
+
+                        def shifted(x, fill):
+                            return tm(lambda v, f: jnp.concatenate(
+                                [f, v[:-1]], axis=0), x, A.full((1, nd), fill))
+
+                        HB = A.maximum(
+                            A.add(tm(lambda x: x[None, :], P["h0"]),
+                                  shifted(Ac, 0.0)),
+                            shifted(Bc, -np.inf))
+                        h_fin = A.maximum(
+                            A.add(P["h0"], tm(lambda x: x[-1], Ac)),
+                            tm(lambda x: x[-1], Bc))
+                waits = A.maximum(A.sub(HB, tm(lambda x: x[:, None], nows)),
+                                  A.zero)
                 if nom is None:
                     nom = P["nom_fixed"]
-                ew = waits[rr, nom]
+                ew = tm(lambda x: x[rr, nom], waits)
 
             # --- CIL pools: one scan, per-config cold flags + dispatches ---
             overflow = jnp.asarray(False)
             if nc:
-                cap = P["busy0"].shape[1]
+                cap = jax.tree.leaves(P["busy0"])[0].shape[1]
                 cidx = jnp.clip(guess, 0, nc - 1)
 
                 def cstep(carry, xs):
                     busy, last, cnt = carry
                     now, ci, isc, occw, occc = xs
-                    idle = (busy <= now) & (now <= last + t_idl)
+                    idle = A.le(busy, now) & A.le(now, A.add(last, t_idl))
                     cold_row = ~idle.any(axis=1)        # per-config, pre-row
                     idle_c = idle[ci]
                     # MRU reuse: first-max == the walk's strict > update
-                    j_warm = jnp.argmax(
-                        jnp.where(idle_c, last[ci], -jnp.inf))
+                    j_warm = A.argmax(
+                        A.where(idle_c, tm(lambda x: x[ci], last), A.ninf), 0)
                     is_cold = ~idle_c.any()
                     j = jnp.where(is_cold, cnt[ci], j_warm)
                     ovf = isc & is_cold & (j >= cap)
                     jc = jnp.minimum(j, cap - 1)
-                    occ = jnp.where(is_cold, occc[ci], occw[ci])
-                    completion = now + occ
+                    occ = A.where(is_cold, tm(lambda x: x[ci], occc),
+                                  tm(lambda x: x[ci], occw))
+                    completion = A.add(now, occ)
                     do = isc & ~ovf
-                    busy = busy.at[ci, jc].set(
-                        jnp.where(do, completion, busy[ci, jc]))
-                    last = last.at[ci, jc].set(
-                        jnp.where(do, completion, last[ci, jc]))
+
+                    def put(x, v):
+                        return x.at[ci, jc].set(jnp.where(do, v, x[ci, jc]))
+
+                    busy = tm(put, busy, completion)
+                    last = tm(put, last, completion)
                     cnt = cnt.at[ci].add(
                         jnp.where(do & is_cold, 1, 0))
                     return (busy, last, cnt), (cold_row, ovf)
@@ -616,40 +822,43 @@ class JaxPlacementCore:
             # --- (R, T) policy-view matrices -------------------------------
             cols_lat, cols_cost, cols_comp = [], [], []
             if nc:
-                cols_lat.append(jnp.where(COLD, P["LATC"], P["LATW"]))
+                cols_lat.append(A.where(COLD, P["LATC"], P["LATW"]))
                 cols_cost.append(P["COSTC"])
                 cols_comp.append(P["COMPC"])
             if has_edge:
-                cols_lat.append((ew + P["ELAT"][rr, nom])[:, None])
-                cols_cost.append(P["ECOST"][rr, nom][:, None])
-                cols_comp.append(P["ECOMP"][rr, nom][:, None])
-            LAT = jnp.concatenate(cols_lat, axis=1)
-            COST = jnp.concatenate(cols_cost, axis=1)
-            COMP = jnp.concatenate(cols_comp, axis=1)
+                def pick(x):
+                    return tm(lambda v: v[rr, nom][:, None], x)
+
+                cols_lat.append(
+                    tm(lambda v: v[:, None], A.add(
+                        ew, tm(lambda v: v[rr, nom], P["ELAT"]))))
+                cols_cost.append(pick(P["ECOST"]))
+                cols_comp.append(pick(P["ECOMP"]))
+
+            def cat(cols):
+                return tm(lambda *v: jnp.concatenate(v, axis=1), *cols)
+
+            LAT, COST, COMP = cat(cols_lat), cat(cols_cost), cat(cols_comp)
 
             # --- surplus bank (the third recurrence; MinLatency only) ------
             s_before = s_fin = None
             if is_minlat:
                 safe_g = jnp.clip(guess, 0, T - 1)
-                delta = jnp.where(guess >= 0,
-                                  P["c_max"] - COST[rr, safe_g], 0.0)
+                delta = A.where(
+                    guess >= 0,
+                    A.sub(P["c_max"], tm(lambda v: v[rr, safe_g], COST)),
+                    A.zero)
                 if seq:
                     def sstep(s, d):
-                        return s + d, s
+                        return A.add(s, d), s
 
                     s_fin, s_before = lax.scan(sstep, P["s0"], delta)
-                elif surplus_kernel:
-                    from repro.kernels.linear_scan.ops import prefix_sum
-
-                    incl = prefix_sum(delta).astype(delta.dtype)
-                    s_before = P["s0"] + jnp.concatenate(
-                        [jnp.zeros(1, delta.dtype), incl[:-1]])
-                    s_fin = P["s0"] + incl[-1]
                 else:
-                    incl = jnp.cumsum(delta)
-                    s_before = P["s0"] + jnp.concatenate(
-                        [jnp.zeros(1, delta.dtype), incl[:-1]])
-                    s_fin = P["s0"] + incl[-1]
+                    incl = A.cumsum(delta)
+                    s_before = A.add(P["s0"], tm(
+                        lambda v, z: jnp.concatenate([z, v[:-1]]),
+                        incl, A.full(1, 0.0)))
+                    s_fin = A.add(P["s0"], tm(lambda v: v[-1], incl))
             return {"nom": nom, "ew": ew, "LAT": LAT, "COST": COST,
                     "COMP": COMP, "COLD": COLD, "s_before": s_before,
                     "s_fin": s_fin, "h_fin": h_fin, "busyF": busyF,
@@ -658,37 +867,40 @@ class JaxPlacementCore:
         return state_fn
 
     def _build_choose(self):
-        jnp = self.jnp
+        jax, jnp, A = self.jax, self.jnp, self.A
         T, edge_col, has_edge = self.T, self.edge_col, self.has_edge
         is_minlat = self.is_minlat
 
+        def col(x):
+            return jax.tree.map(lambda v: v[:, None], x)
+
         def choose_fn(LAT, COST, allowed, deadline, valid):
-            R = LAT.shape[0]
+            R = valid.shape[0]
             if is_minlat:
-                feas = COST <= allowed[:, None]
+                feas = A.le(COST, col(allowed))
                 none_f = ~feas.any(axis=1)
                 if has_edge:
                     onehot = (jnp.arange(T) == edge_col)[None, :]
                     feas = jnp.where(none_f[:, None], onehot, feas)
                 else:
                     feas = feas | none_f[:, None]
-                l1 = jnp.where(feas, LAT, jnp.inf)
-                lmin = l1.min(axis=1)
-                tie = feas & (LAT == lmin[:, None])
-                c2 = jnp.where(tie, COST, jnp.inf)
-                cmin = c2.min(axis=1)
-                final = tie & (COST == cmin[:, None])
+                l1 = A.where(feas, LAT, A.inf)
+                lmin = A.reduce_min(l1, 1)
+                tie = feas & A.eq(LAT, col(lmin))
+                c2 = A.where(tie, COST, A.inf)
+                cmin = A.reduce_min(c2, 1)
+                final = tie & A.eq(COST, col(cmin))
                 code = final.argmax(axis=1).astype(jnp.int32)
                 feas_out = jnp.ones(R, dtype=bool)
             else:  # MinCostPolicy (edge column guaranteed by eligibility)
-                feas = LAT <= deadline
+                feas = A.le(LAT, deadline)
                 any_f = feas.any(axis=1)
-                c1 = jnp.where(feas, COST, jnp.inf)
-                cmin = c1.min(axis=1)
-                tie = feas & (COST == cmin[:, None])
-                l2 = jnp.where(tie, LAT, jnp.inf)
-                lmin = l2.min(axis=1)
-                final = tie & (LAT == lmin[:, None])
+                c1 = A.where(feas, COST, A.inf)
+                cmin = A.reduce_min(c1, 1)
+                tie = feas & A.eq(COST, col(cmin))
+                l2 = A.where(tie, LAT, A.inf)
+                lmin = A.reduce_min(l2, 1)
+                final = tie & A.eq(LAT, col(lmin))
                 code = final.argmax(axis=1).astype(jnp.int32)
                 code = jnp.where(any_f, code, edge_col)
                 feas_out = any_f
@@ -697,7 +909,8 @@ class JaxPlacementCore:
         return choose_fn
 
     def _build_finalize(self):
-        jnp = self.jnp
+        jax, jnp, A = self.jax, self.jnp, self.A
+        tm = jax.tree.map
         nc, T = self.n_cloud, self.T
         edge_col, has_edge = self.edge_col, self.has_edge
         is_minlat = self.is_minlat
@@ -707,9 +920,13 @@ class JaxPlacementCore:
             R = code.shape[0]
             rr = jnp.arange(R)
             safe = jnp.clip(code, 0, T - 1)
+
+            def chosen(x):
+                return tm(lambda v: v[rr, safe], x)
+
             res = {"code": code, "overflow": st["overflow"],
-                   "lat": st["LAT"][rr, safe], "cost": st["COST"][rr, safe],
-                   "comp": st["COMP"][rr, safe], "allowed": allowed,
+                   "lat": chosen(st["LAT"]), "cost": chosen(st["COST"]),
+                   "comp": chosen(st["COMP"]), "allowed": allowed,
                    "feas": feas}
             if is_minlat:
                 res["s_fin"] = st["s_fin"]
@@ -718,13 +935,13 @@ class JaxPlacementCore:
             if has_edge:
                 is_edge_ch = code == edge_col
                 res["cold"] = jnp.where(is_edge_ch, False, cold)
-                res["wait"] = jnp.where(is_edge_ch, st["ew"], 0.0)
+                res["wait"] = A.where(is_edge_ch, st["ew"], A.zero)
                 res["nom"] = st["nom"]
                 res["gcode"] = jnp.where(is_edge_ch, nc + st["nom"], code)
                 res["h_fin"] = st["h_fin"]
             else:
                 res["cold"] = cold
-                res["wait"] = jnp.zeros(R)
+                res["wait"] = A.full(R, 0.0)
                 res["gcode"] = code
             if nc:
                 res["busyF"], res["lastF"], res["cntF"] = \
@@ -747,28 +964,46 @@ class JaxPlacementCore:
         again), so compaction keeps exactly the records the host list would
         hold — in the same relative (list) order, preserving MRU first-max
         tie-breaks."""
-        jnp = self.jnp
-        nc, t_idl = self.n_cloud, self.t_idl
+        jax, jnp, A = self.jax, self.jnp, self.A
+        tm = jax.tree.map
+        nc, t_idl = self.n_cloud, A.const(self.t_idl)
 
         def compact(busy, last, cnt, t_last):
-            cap = busy.shape[1]
+            cap = jax.tree.leaves(busy)[0].shape[1]
             slots = jnp.arange(cap)
             in_use = slots[None, :] < cnt[:, None]
-            keep = in_use & ((t_last < busy) | (t_last <= last + t_idl))
+            keep = in_use & (A.lt(t_last, busy)
+                             | A.le(t_last, A.add(last, t_idl)))
             # stable scatter: kept slot -> its rank; dropped -> the spill
             # column (sliced off below)
             d = jnp.where(keep, jnp.cumsum(keep, axis=1) - 1, cap)
             rows = jnp.arange(nc)[:, None]
-            nb = jnp.full((nc, cap + 1), jnp.inf,
-                          busy.dtype).at[rows, d].set(busy)[:, :cap]
-            nl = jnp.full((nc, cap + 1), -jnp.inf,
-                          last.dtype).at[rows, d].set(last)[:, :cap]
-            return nb, nl, keep.sum(axis=1).astype(cnt.dtype)
+
+            def scatter(x, fill):
+                return tm(lambda v, f: f.at[rows, d].set(v)[:, :cap],
+                          x, A.full((nc, cap + 1), fill))
+
+            return (scatter(busy, np.inf), scatter(last, -np.inf),
+                    keep.sum(axis=1).astype(cnt.dtype))
 
         return compact
 
+    def _build_shift(self):
+        """Move resident times to a new chunk base: ``t += old - new``
+        (two-float only; ``±inf`` sentinels stay put)."""
+        A = self.A
+
+        def shift(S, delta):
+            out = dict(S)
+            for k in ("busy0", "last0", "h0"):
+                if k in out:
+                    out[k] = A.add(out[k], delta)
+            return out
+
+        return shift
+
     def _build_place(self):
-        jnp, lax = self.jnp, self.lax
+        jax, jnp, lax, A = self.jax, self.jnp, self.lax, self.A
         is_minlat = self.is_minlat
         state_fn = self._state_fn
         choose_fn = self._choose_fn
@@ -777,22 +1012,16 @@ class JaxPlacementCore:
         def step(guess, P):
             st = state_fn(guess, P)
             if is_minlat:
-                allowed = P["c_max"] + P["alpha"] * st["s_before"]
+                allowed = A.add(P["c_max"], A.mul(P["alpha"],
+                                                  st["s_before"]))
             else:
-                allowed = jnp.full(guess.shape[0], jnp.inf)
+                allowed = A.full(guess.shape[0], np.inf)
             code, feas = choose_fn(st["LAT"], st["COST"], allowed,
                                    P["deadline"], P["valid"])
             return st, code, feas, allowed
 
-        def place(P, S):
-            # S carries the sequential-state seed (CIL pools, edge horizons,
-            # surplus) split out so the jit can DONATE its buffers — resident
-            # streams thread chunk k's final arrays in as chunk k+1's seed
-            # with zero steady-state allocation. Callers must treat S as
-            # consumed (place_chunk keeps a tiny device-side backup for the
-            # overflow retry).
-            P = {**P, **S}
-            R = P["nows"].shape[0]
+        def fixed_point(P):
+            R = P["valid"].shape[0]
             g0 = jnp.full(R, -1, dtype=jnp.int32)
             g1 = step(g0, P)[1]
 
@@ -811,12 +1040,57 @@ class JaxPlacementCore:
             res["converged"] = ~jnp.any(code != gF)
             return res
 
+        def place(P, S):
+            # S carries the sequential-state seed (CIL pools, edge horizons,
+            # surplus) split out so the jit can DONATE its buffers — resident
+            # streams thread chunk k's final arrays in as chunk k+1's seed
+            # with zero steady-state allocation. Callers must treat S as
+            # consumed (place_chunk keeps a tiny device-side backup for the
+            # overflow retry).
+            #
+            # The chunk is decided in PLACE_BLOCK-row blocks, in order, each
+            # a fixed point seeded with the previous block's final state: the
+            # fixed point is the exact sequential trajectory at any blocking,
+            # and a pass repairs only a few rows when decisions feed back
+            # (a binding surplus bank, warm-container chains), so passes
+            # grow with the rows a fixed point spans.
+            R = P["valid"].shape[0]
+            B = min(R, PLACE_BLOCK)
+            const = {k: P[k] for k in _SCALAR_INPUTS}
+            rows = {k: v for k, v in P.items() if k not in _SCALAR_INPUTS}
+            blocks = jax.tree.map(
+                lambda v: v.reshape((R // B, B) + v.shape[1:]), rows)
+
+            def block(S, Pb):
+                res = fixed_point({**const, **Pb, **S})
+                S = dict(S)
+                for seed, final in _STATE_OUTPUTS:
+                    if final in res:
+                        S[seed] = res.pop(final)
+                res.pop("cnt_max", None)
+                return S, res
+
+            S, out = lax.scan(block, S, blocks)
+            res = {k: jax.tree.map(
+                lambda v: v.reshape((R,) + v.shape[2:]), v)
+                for k, v in out.items()
+                if k not in ("overflow", "iters", "converged")}
+            res["overflow"] = out["overflow"].any()
+            res["iters"] = out["iters"].sum()
+            res["converged"] = out["converged"].all()
+            for seed, final in _STATE_OUTPUTS:
+                if seed in S:
+                    res[final] = S[seed]
+            if self.n_cloud:
+                res["cnt_max"] = S["cnt0"].max()
+            return res
+
         return place
 
     def _run_interpret(self, P, R: int) -> dict:
         """Host-driven fixed point over the jitted FMA-free pieces: bit-exact
         (the α·s_before multiply runs op-by-op) at compiled-scan speed."""
-        jax, jnp = self.jax, self.jnp
+        jax, jnp, A = self.jax, self.jnp, self.A
         g = jnp.asarray(np.full(R, -1, np.int32))
         g_np = np.asarray(g)
         st = code = feas = allowed = None
@@ -826,9 +1100,10 @@ class JaxPlacementCore:
             st = self._state(g, P)
             if self.is_minlat:
                 with jax.disable_jit():
-                    allowed = P["c_max"] + P["alpha"] * st["s_before"]
+                    allowed = A.add(P["c_max"],
+                                    A.mul(P["alpha"], st["s_before"]))
             else:
-                allowed = jnp.full(R, jnp.inf)
+                allowed = A.full(R, np.inf)
             code, feas = self._choose(st["LAT"], st["COST"], allowed,
                                       P["deadline"], P["valid"])
             iters += 1
@@ -845,23 +1120,30 @@ class JaxPlacementCore:
         return res
 
     # ------------------------------------------------------------ residency
+    def _stage(self, host, R: int) -> tuple:
+        """Padded device task columns ``(sizes, nbytes, nows, valid)`` of one
+        chunk; times relative to the chunk's base (see ``_base``)."""
+        _, nows_np, sizes_np, nbytes_np = host
+        n = nows_np.shape[0]
+        pad = R - n
+        A = self.A
+        return (A.dev(np.pad(sizes_np, (0, pad), mode="edge")),
+                A.dev(np.pad(nbytes_np, (0, pad), mode="edge")),
+                A.dev(np.pad(nows_np, (0, pad), mode="edge"),
+                      self._base(nows_np)),
+                self.jnp.asarray(np.arange(R) < n))
+
     def stage_chunk(self, tasks) -> dict:
         """Host prep + device upload for one chunk — engine-state-free, so
         ``runtime._prefetched_chunks`` can run it on the transfer thread
         while the previous chunk's fixed point occupies the device (the x64
         scope is thread-local and re-entered here). The bundle reaches
         ``place_chunk`` via ``engine._jax_staged``."""
-        jax = self.jax
-        n = len(tasks)
         host = task_arrays(tasks)
-        _, nows_np, sizes_np, nbytes_np = host
-        R = max(PAD_MIN, _next_pow2(n))
-        pad = R - n
+        n = len(tasks)
         with self._scope():
-            dev = (jax.device_put(np.pad(sizes_np, (0, pad), mode="edge")),
-                   jax.device_put(np.pad(nbytes_np, (0, pad), mode="edge")),
-                   jax.device_put(np.pad(nows_np, (0, pad), mode="edge")),
-                   jax.device_put(np.arange(R) < n))
+            dev = self._stage(host, max(PAD_MIN, _next_pow2(n)))
+            dev = self.jax.device_put(dev)
         return {"host": host, "dev": dev, "n": n}
 
     def sync_host(self, reason: str = "external") -> bool:
@@ -873,15 +1155,16 @@ class JaxPlacementCore:
         if rs is None:
             return False
         self._resident = None
+        A = self.A
         if self.is_minlat and rs.s is not None:
-            rs.policy.surplus = float(rs.s)
+            rs.policy.surplus = float(A.host(rs.s))
         if self.has_edge and rs.h is not None:
-            h = np.asarray(rs.h)
+            h = A.host(rs.h) + rs.base
             for d, e in enumerate(self.edges):
                 rs.queues[e.name].horizon_ms = float(h[d])
         if self.n_cloud and rs.busy is not None:
-            self._commit_pools(rs.cil, np.asarray(rs.busy),
-                               np.asarray(rs.last), np.asarray(rs.cnt),
+            self._commit_pools(rs.cil, A.host(rs.busy) + rs.base,
+                               A.host(rs.last) + rs.base, np.asarray(rs.cnt),
                                rs.t_last)
         self.state_syncs += 1
         if reason == "fallback":
@@ -903,19 +1186,23 @@ class JaxPlacementCore:
             else:
                 cil.containers.pop(c.name, None)
 
-    def _seed_state(self, rs, pools, cap, edge_queues, dev_names, policy):
+    def _seed_state(self, rs, pools, cap, edge_queues, dev_names, policy,
+                    base):
         """The (donated) sequential-state seed ``S`` — from resident device
         arrays when a valid ``DeviceStreamState`` is held (growing pool
-        width device-side when ``cap`` outgrew it), else from host state."""
-        jnp = self.jnp
+        width device-side when ``cap`` outgrew it), else from host state
+        rebased to ``base``."""
+        jax, jnp, A = self.jax, self.jnp, self.A
         S: dict = {}
         if rs is not None and self.n_cloud:
             busy, last = rs.busy, rs.last
-            have = int(busy.shape[1])
+            have = int(jax.tree.leaves(busy)[0].shape[1])
             if cap > have:
                 grow = ((0, 0), (0, cap - have))
-                busy = jnp.pad(busy, grow, constant_values=np.inf)
-                last = jnp.pad(last, grow, constant_values=-np.inf)
+                busy = jax.tree.map(lambda v: jnp.pad(
+                    v, grow, constant_values=np.inf), busy)
+                last = jax.tree.map(lambda v: jnp.pad(
+                    v, grow, constant_values=-np.inf), last)
             S["busy0"], S["last0"], S["cnt0"] = busy, last, rs.cnt
         elif self.n_cloud:
             busy0 = np.full((self.n_cloud, cap), np.inf)
@@ -926,22 +1213,34 @@ class JaxPlacementCore:
                     busy0[ci, j] = rec.busy_until
                     last0[ci, j] = rec.last_completion
                 cnt0[ci] = len(recs)
-            S["busy0"] = jnp.asarray(busy0)
-            S["last0"] = jnp.asarray(last0)
+            S["busy0"] = A.dev(busy0, base)
+            S["last0"] = A.dev(last0, base)
             S["cnt0"] = jnp.asarray(cnt0)
         else:
-            S["busy0"] = jnp.zeros((0, cap))
-            S["last0"] = jnp.zeros((0, cap))
+            S["busy0"] = A.full((0, cap), 0.0)
+            S["last0"] = A.full((0, cap), 0.0)
             S["cnt0"] = jnp.zeros(0, dtype=jnp.int32)
         if self.has_edge:
-            S["h0"] = rs.h if rs is not None else jnp.asarray(np.array(
-                [edge_queues[nm].horizon_ms for nm in dev_names]))
+            S["h0"] = rs.h if rs is not None else A.dev(np.array(
+                [edge_queues[nm].horizon_ms for nm in dev_names]), base)
         if self.is_minlat:
             # np scalar, not python float: a strongly-typed aval, so host-
             # and resident-seeded calls share one jit trace per pool shape
             S["s0"] = rs.s if rs is not None \
-                else jnp.asarray(np.float64(policy.surplus))
+                else A.dev(np.float64(policy.surplus))
         return S
+
+    def _rebase(self, rs, base: float) -> None:
+        """Carry resident times over to this chunk's base (two-float)."""
+        if rs.base == base:
+            return
+        S = {"busy0": rs.busy, "last0": rs.last, "h0": rs.h}
+        S = self._shift({k: v for k, v in S.items() if v is not None},
+                        self.A.const(rs.base - base))
+        rs.busy = S.get("busy0")
+        rs.last = S.get("last0")
+        rs.h = S.get("h0")
+        rs.base = base
 
     # ----------------------------------------------------------- chunk entry
     def place_chunk(self, engine, tasks, edge_queues, interpret: bool):
@@ -956,15 +1255,16 @@ class JaxPlacementCore:
             RoundRobinBalancer,
         )
 
-        jnp = self.jnp
+        jax, jnp, A = self.jax, self.jnp, self.A
         n = len(tasks)
         staged = engine.__dict__.pop("_jax_staged", None)
         if staged is not None and staged[0] is not tasks:
             staged = None       # stale prefetch for some other chunk
         if staged is not None:
-            task_idx, nows_np, sizes_np, nbytes_np = staged[1]["host"]
+            host = staged[1]["host"]
         else:
-            task_idx, nows_np, sizes_np, nbytes_np = task_arrays(tasks)
+            host = task_arrays(tasks)
+        task_idx, nows_np = host[0], host[1]
         if not self.has_edge and self.is_minlat and not self.cloud:
             self.sync_host("fallback")
             return None  # nothing to choose from — let the walk raise
@@ -1009,6 +1309,7 @@ class JaxPlacementCore:
 
         R = max(PAD_MIN, _next_pow2(n))
         pad = R - n
+        base = self._base(nows_np)
         cloud_names = [c.name for c in self.cloud]
         dev_names = [e.name for e in self.edges]
         pools = [cil.containers.get(nm, []) for nm in cloud_names]
@@ -1020,51 +1321,50 @@ class JaxPlacementCore:
             cap = _next_pow2(max(self._cap_hint, POOL_MIN_CAP))
 
         with self._scope():
+            if rs is not None:
+                self._rebase(rs, base)
             if staged is not None:
                 sizes, nbytes, nows_d, valid_d = staged[1]["dev"]
             else:
-                sizes = jnp.asarray(np.pad(sizes_np, (0, pad), mode="edge"))
-                nbytes = jnp.asarray(np.pad(nbytes_np, (0, pad), mode="edge"))
-                nows_d = jnp.asarray(np.pad(nows_np, (0, pad), mode="edge"))
-                valid_d = jnp.asarray(np.arange(R) < n)
+                sizes, nbytes, nows_d, valid_d = self._stage(host, R)
             if interpret:
                 # op-by-op: the predict pass is where the FMA-prone
                 # multiplies live (ridge, pricing); eager execution keeps
                 # every op individually rounded, bit-identical to numpy
-                with self.jax.disable_jit():
+                with jax.disable_jit():
                     P = dict(self._predict(sizes, nbytes))
             else:
                 P = dict(self._predict(sizes, nbytes))
             P["nows"] = nows_d
             P["valid"] = valid_d
             if self.has_edge:
-                P["ECOST"] = jnp.zeros((R, self.n_dev))
+                P["ECOST"] = A.full((R, self.n_dev), 0.0)
                 if nom_fixed is not None:
                     P["nom_fixed"] = jnp.asarray(np.pad(
                         nom_fixed, (0, pad)).astype(np.int32))
                 else:
                     P["nom_fixed"] = jnp.zeros(R, dtype=jnp.int32)
             if self.is_minlat:
-                P["c_max"] = float(policy.c_max)
-                P["alpha"] = float(policy.alpha)
-                P["deadline"] = 0.0
+                P["c_max"] = A.const(policy.c_max)
+                P["alpha"] = A.const(policy.alpha)
+                P["deadline"] = A.const(0.0)
             else:
-                P["c_max"] = 0.0
-                P["alpha"] = 0.0
-                P["deadline"] = float(policy.deadline_ms)
+                P["c_max"] = A.const(0.0)
+                P["alpha"] = A.const(0.0)
+                P["deadline"] = A.const(policy.deadline_ms)
             res = None
             compacted = rs is None   # host seeds arrive freshly reaped
             while True:
                 if cap < max_existing + 1:
                     cap = _next_pow2(max_existing + 1)
                 S = self._seed_state(rs, pools, cap, edge_queues, dev_names,
-                                     policy)
+                                     policy, base)
                 if interpret:
                     res = self._run_interpret({**P, **S}, R)
                 else:
                     # the jit DONATES S; a resident seed must survive an
                     # overflow retry, so keep a (tiny) device-side copy
-                    backup = ({k: jnp.copy(v) for k, v in S.items()}
+                    backup = (jax.tree.map(jnp.copy, S)
                               if rs is not None else None)
                     res = self._place(P, S)
                 if not bool(res["overflow"]) and bool(res["converged"]):
@@ -1078,7 +1378,7 @@ class JaxPlacementCore:
                         # donated seed was consumed — restore from backup
                         rs.busy, rs.last, rs.cnt = (
                             backup["busy0"], backup["last0"], backup["cnt0"])
-                        rs.cap = int(backup["busy0"].shape[1])
+                        rs.cap = int(jax.tree.leaves(rs.busy)[0].shape[1])
                         cap = rs.cap
                         if "h0" in backup:
                             rs.h = backup["h0"]
@@ -1091,7 +1391,8 @@ class JaxPlacementCore:
                         # keeps steady-state pool width bounded by the LIVE
                         # container count, all without a host sync
                         rs.busy, rs.last, rs.cnt = self._compact(
-                            rs.busy, rs.last, rs.cnt, rs.t_last)
+                            rs.busy, rs.last, rs.cnt,
+                            A.const(rs.t_last - rs.base))
                         rs.cnt_max = int(np.asarray(rs.cnt).max())
                         max_existing = rs.cnt_max
                         compacted = True
@@ -1106,15 +1407,16 @@ class JaxPlacementCore:
                 cap = new_cap
             self._cap_hint = cap
 
-            out = {k: np.asarray(res[k])[:n] for k in
-                   ("gcode", "lat", "cost", "cold", "comp", "wait",
-                    "feas", "allowed")}
+            out = {k: A.host(res[k])[:n] for k in
+                   ("lat", "cost", "comp", "wait", "allowed")}
+            out.update({k: np.asarray(res[k])[:n] for k in
+                        ("gcode", "cold", "feas")})
             iters = int(res["iters"])
             t_last = float(nows_np[-1])
             if residency:
                 # ---- stay resident: committed state LIVES on device -------
                 if rs is None:
-                    rs = DeviceStreamState()
+                    rs = DeviceStreamState(base=base)
                 if self.n_cloud:
                     rs.busy, rs.last, rs.cnt = \
                         res["busyF"], res["lastF"], res["cntF"]
@@ -1133,14 +1435,14 @@ class JaxPlacementCore:
             else:
                 # ---- commit host state (the numpy accept step, once) ------
                 if self.is_minlat:
-                    policy.surplus = float(res["s_fin"])
+                    policy.surplus = float(A.host(res["s_fin"]))
                 if self.has_edge:
-                    h_fin = np.asarray(res["h_fin"])
+                    h_fin = A.host(res["h_fin"]) + base
                     for d, nm in enumerate(dev_names):
                         edge_queues[nm].horizon_ms = float(h_fin[d])
                 if self.n_cloud:
-                    self._commit_pools(cil, np.asarray(res["busyF"]),
-                                       np.asarray(res["lastF"]),
+                    self._commit_pools(cil, A.host(res["busyF"]) + base,
+                                       A.host(res["lastF"]) + base,
                                        np.asarray(res["cntF"]), t_last)
                 self.chunk_commits += 1
 
@@ -1151,6 +1453,9 @@ class JaxPlacementCore:
                                  "walked": 0, "n": n}
         self.last_stats = {"n": n, "passes": iters + 1, "rows": R,
                            "pool_cap": cap, "interpret": interpret,
+                           "two_float": A.df,
+                           "gbrt_kernel": self.use_gbrt_kernel,
+                           "kernel_interpret": self.kernel_interpret,
                            "resident": residency,
                            "staged": staged is not None}
         engine.jax_stats = dict(self.last_stats)
@@ -1160,13 +1465,13 @@ class JaxPlacementCore:
             n_cloud=self.n_cloud,
             task_idx=task_idx,
             target_codes=out["gcode"].astype(np.int64),
-            latency_ms=out["lat"].astype(np.float64),
-            cost=out["cost"].astype(np.float64),
+            latency_ms=out["lat"],
+            cost=out["cost"],
             cold=out["cold"].astype(bool),
-            comp_ms=out["comp"].astype(np.float64),
-            queue_wait_ms=out["wait"].astype(np.float64),
+            comp_ms=out["comp"],
+            queue_wait_ms=out["wait"],
             feasible=out["feas"].astype(bool),
-            allowed_cost=out["allowed"].astype(np.float64),
+            allowed_cost=out["allowed"],
             edge_device_codes=nom_out,
             batch_factory=lambda pred=engine.predictor, ts=tasks:
                 pred.predict_batch(ts),
@@ -1176,9 +1481,8 @@ class JaxPlacementCore:
 # ------------------------------------------------------------------ caching
 def core_for(engine) -> JaxPlacementCore | None:
     """The engine's cached core, rebuilt when model identities / policy /
-    kernel mode change; ``None`` when jax or the engine shape is ineligible."""
-    if not available():
-        return None
+    kernel mode change; ``None`` when the engine shape is outside the core
+    (``CoreIneligible``). Any other build failure raises."""
     key = _engine_key(engine)
     hit = engine.__dict__.get("_jax_core_cache")
     if hit is not None and hit[0] == key:

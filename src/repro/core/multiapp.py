@@ -19,7 +19,11 @@ independent and can execute concurrently.
 - **processes** (``use_processes=True``): full isolation for workloads whose
   Python fraction defeats thread overlap. Shards must then carry *factories*
   (picklable callables building the runtime/workload in the child) rather
-  than live objects.
+  than live objects. Children are *spawned* and numpy-only by
+  construction: a chip belongs to one process, and a parent that touched
+  JAX holds it, so the parent pins the children's GBRT route to the numpy
+  tree walk (``GBRT_KERNEL_MODE = "off"``) and a shard whose engine asks
+  for a jax backend is refused. No child initializes a JAX backend.
 - **sequential** (``parallel=False``): the baseline the speedup floor in
   ``benchmarks/bench_runtime.py`` is measured against.
 
@@ -30,6 +34,7 @@ report.
 
 from __future__ import annotations
 
+import multiprocessing
 import time
 from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor
 from dataclasses import dataclass
@@ -72,8 +77,29 @@ class AppShard:
 
 
 def _serve_shard(shard: AppShard) -> tuple[str, SimulationResult, float, dict]:
-    """Top-level so process pools can pickle it; runs one shard end to end."""
+    """Runs one shard end to end."""
+    return _serve(shard, shard.resolve_runtime())
+
+
+def _child_init(gbrt_kernel_mode: str) -> None:
+    """Process-pool initializer: the GBRT route the parent decided."""
+    from repro.core import predictor
+
+    predictor.GBRT_KERNEL_MODE = gbrt_kernel_mode
+
+
+def _serve_shard_in_child(shard: AppShard):
+    """Top-level so process pools can pickle it: one shard, numpy only."""
     rt = shard.resolve_runtime()
+    if rt.engine.array_backend != "numpy":
+        raise ValueError(
+            f"shard {shard.name!r}: process-mode shards serve on numpy "
+            f"(array_backend={rt.engine.array_backend!r}); a child must not "
+            "touch the device the parent holds — use threads")
+    return _serve(shard, rt)
+
+
+def _serve(shard: AppShard, rt: PlacementRuntime):
     t0 = time.perf_counter()
     res = rt.serve_stream(shard.resolve_workload(),
                           chunk_size=shard.chunk_size,
@@ -172,13 +198,16 @@ class ShardedRuntime:
                             f"shard {s.name!r}: use_processes=True requires "
                             "runtime and workload factories (callables) so "
                             "the child process builds its own copies")
-                pool_cls = ProcessPoolExecutor
-                mode = "process"
+                pool = ProcessPoolExecutor(
+                    max_workers=workers,
+                    mp_context=multiprocessing.get_context("spawn"),
+                    initializer=_child_init, initargs=("off",))
+                fn, mode = _serve_shard_in_child, "process"
             else:
-                pool_cls = ThreadPoolExecutor
-                mode = "thread"
-            with pool_cls(max_workers=workers) as pool:
-                outs = list(pool.map(_serve_shard, self.shards))
+                pool = ThreadPoolExecutor(max_workers=workers)
+                fn, mode = _serve_shard, "thread"
+            with pool:
+                outs = list(pool.map(fn, self.shards))
         elapsed = time.perf_counter() - t0
         return ShardedResult(
             results={name: res for name, res, _, _ in outs},

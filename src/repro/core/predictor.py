@@ -29,9 +29,9 @@ Two prediction paths:
 
 On the batched path the GBRT compute model can additionally be routed through
 the ``repro.kernels.gbrt_predict`` Pallas kernel (see ``GBRT_KERNEL_MODE``):
-on a TPU backend, batches of ≥ ``GBRT_KERNEL_MIN_BATCH`` rows run the one-hot
-matmul ensemble kernel; everywhere else the vectorized numpy tree walk is the
-fallback (it is both exact and faster than interpret-mode Pallas on CPU).
+on a TPU backend, batches of ≥ ``GBRT_KERNEL_MIN_BATCH`` rows run the
+two-float ensemble kernel; everywhere else the vectorized numpy tree walk is
+the fallback (it is both exact and faster than interpret-mode Pallas on CPU).
 
 The ``quantile`` option is a beyond-paper extension (the paper's stated future
 work): predict a latency quantile instead of the mean, so placement can hedge
@@ -59,9 +59,12 @@ EDGE = "edge"
 #   "auto"  — use the kernel when a real TPU backend is attached and the batch
 #             has at least GBRT_KERNEL_MIN_BATCH rows; numpy tree walk
 #             otherwise (CPU interpret-mode Pallas is slower than numpy, and
-#             the f32 kernel would break exact scalar/batch decision parity);
+#             the two-float kernel agrees with the float64 walk to ~1e-14,
+#             not bit for bit, which exact scalar/batch parity needs);
 #   "force" — always use the kernel (tests / TPU microbenchmarks);
-#   "off"   — always use the numpy tree walk.
+#   "off"   — always use the numpy tree walk (a reference that must not run
+#             the kernel under test; worker processes that must not touch
+#             the device).
 GBRT_KERNEL_MODE = "auto"
 GBRT_KERNEL_MIN_BATCH = 4096
 
@@ -71,15 +74,14 @@ _TPU_BACKEND: bool | None = None
 
 def _tpu_backend() -> bool:
     """Cached TPU-backend probe — importing jax costs ~0.7 s, so the serving
-    path must only ever pay it once per process."""
+    path must only ever pay it once per process. Initializes the process's
+    JAX backend: a process that must stay off the device sets
+    ``GBRT_KERNEL_MODE = "off"``, which never reaches this probe."""
     global _TPU_BACKEND
     if _TPU_BACKEND is None:
-        try:
-            import jax
+        import jax
 
-            _TPU_BACKEND = jax.default_backend() == "tpu"
-        except Exception:
-            _TPU_BACKEND = False
+        _TPU_BACKEND = jax.default_backend() == "tpu"
     return _TPU_BACKEND
 
 
@@ -188,13 +190,9 @@ def gbrt_batch_predict(model, feats: np.ndarray) -> np.ndarray:
     if (mode != "off" and hasattr(model, "thresholds")
             and (mode == "force"
                  or (feats.shape[0] >= GBRT_KERNEL_MIN_BATCH and _tpu_backend()))):
-        try:
-            from repro.kernels.gbrt_predict.ops import gbrt_predict
+        from repro.kernels.gbrt_predict.ops import gbrt_predict
 
-            return np.asarray(gbrt_predict(model, feats), dtype=np.float64)
-        except Exception:
-            if mode == "force":
-                raise
+        return gbrt_predict(model, feats)
     if (hasattr(model, "const1_table") and feats.ndim == 2
             and feats.shape[1] == 2 and feats.shape[0] > 0
             and np.all(feats[:, 1] == feats[0, 1])):

@@ -17,6 +17,8 @@ speculate-and-repair passes out of these.
 
 from __future__ import annotations
 
+import operator
+
 import numpy as np
 
 
@@ -95,7 +97,7 @@ def surplus_trajectory(s0: float, c_max: float,
     return np.cumsum(np.concatenate(([s0], c_max - chosen_cost)))
 
 
-def maxplus_combine(x, y, maximum=np.maximum):
+def maxplus_combine(x, y, maximum=np.maximum, add=operator.add):
     """Associative combine for the FIFO/edge-horizon recurrence in (max, +).
 
     ``h_i = max(h_{i-1}, now_i) + comp_i`` (a push) and ``h_i = h_{i-1}`` (no
@@ -107,8 +109,8 @@ def maxplus_combine(x, y, maximum=np.maximum):
     Reassociating float sums is NOT bit-stable, so the device core only uses
     this form under its decision-equality contract (``SCAN_MODE="assoc"``);
     the sequential folds stay the bit-parity path. Pass ``jnp.maximum`` to use
-    it inside a jit trace.
+    it inside a jit trace, and two-float ``maximum``/``add`` for pairs.
     """
     a1, b1 = x
     a2, b2 = y
-    return a1 + a2, maximum(b1 + a2, b2)
+    return add(a1, a2), maximum(add(b1, a2), b2)

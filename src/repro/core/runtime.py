@@ -937,10 +937,7 @@ def _prefetched_chunks(it, eng, counters: dict):
         if len(chunk):
             core = _engine_core(eng)  # appears once the first chunk compiled
             if core is not None:
-                try:
-                    staged = core.stage_chunk(chunk)
-                except Exception:  # staging is an optimization, never fatal
-                    staged = None
+                staged = core.stage_chunk(chunk)
         return chunk, staged
 
     with ThreadPoolExecutor(max_workers=1) as ex:
@@ -1107,7 +1104,8 @@ class PlacementRuntime:
           transfer with device compute.
 
         ``stream_stats["residency"]`` afterwards reports the resident-chunk
-        / sync / prefetch counters for this stream.
+        / sync / prefetch counters for this stream, and ``fallback_chunks``:
+        chunks the core refused on semantic grounds and numpy served.
         """
         if chunk_size < 1:
             raise ValueError(f"chunk_size must be >= 1, got {chunk_size}")
@@ -1199,17 +1197,15 @@ class PlacementRuntime:
         if use_device:
             core = _engine_core(eng)
             if core is not None:
-                stats["residency"] = {
-                    "enabled": residency,
-                    "resident_chunks": core.resident_chunks
-                    - base.get("resident_chunks", 0),
-                    "state_syncs": core.state_syncs
-                    - base.get("state_syncs", 0),
-                    "fallback_syncs": core.fallback_syncs
-                    - base.get("fallback_syncs", 0),
-                    "chunk_commits": core.chunk_commits
-                    - base.get("chunk_commits", 0),
-                    "prefetched": pf["prefetched"]}
+                r = {k: getattr(core, k) - base.get(k, 0)
+                     for k in ("resident_chunks", "state_syncs",
+                               "fallback_syncs", "chunk_commits")}
+                # chunks served on numpy by a semantic refusal (hedged or
+                # custom policy, out-of-order arrivals, ...)
+                r["fallback_chunks"] = stats["chunks"] \
+                    - r["resident_chunks"] - r["chunk_commits"]
+                stats["residency"] = {"enabled": residency, **r,
+                                      "prefetched": pf["prefetched"]}
         self.stream_stats = stats
         return self.result(arena.finish())
 

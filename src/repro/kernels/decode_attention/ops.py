@@ -2,9 +2,9 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.decode_attention.kernel import decode_attention_bhd
 
 
@@ -12,7 +12,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, block_k: int = 256,
                      interpret: bool | None = None):
     """q: (B, 1, H, D); caches: (B, S, Hkv, D); lengths: (B,) -> (B, 1, H, D)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     B, _, H, D = q.shape
     S = k_cache.shape[1]
     bk = min(block_k, S)

@@ -10,14 +10,14 @@ body the fleet runs.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_bhsd
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    return interpret_mode()
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,

@@ -5,14 +5,19 @@ from __future__ import annotations
 import threading
 import weakref
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import dfloat, interpret_mode
 from repro.kernels.gbrt_predict.kernel import (
+    CFG_BASE,
+    CFG_LR,
+    CFG_MEM,
+    CFG_WIDTH,
     gbrt_predict_blocked,
-    gbrt_predict_multi,
 )
+
+_BIG = 3.0e38  # finite stand-in for +inf pass-through thresholds
 
 # Device-operand caches, keyed on model identity with a weakref guard — the
 # ``_CONST1_TABLES`` idiom (see ``repro.core.predictor``): an online refit
@@ -47,95 +52,98 @@ def _cached(cache: dict, key, models, build):
     return val
 
 
-def kernel_operands(model) -> tuple:
-    """Device-ready ensemble operands for ``gbrt_predict_blocked``.
+def _tree_tables(model, n_trees: int, depth: int) -> tuple:
+    """One model's ensemble padded to ``(n_trees, depth)`` and flattened:
+    ``(features (T*I,) i32, thr_hi, thr_lo (T*I,) f32, leaf_hi, leaf_lo
+    (T*L,) f32)``, thresholds and leaves as two-float pairs.
 
-    Returns ``(features i32, thresholds f32, leaves f32)`` as jnp arrays.
-    +inf thresholds mark pass-through nodes; the kernel compares in f32, so
-    thresholds are clipped to the finite f32 range host-side. Shared by the
-    wrapper below and the device-resident placement core
-    (``repro.core.jax_core``); hosted once per model identity (weakref-guarded
-    — refit-by-swap invalidates automatically).
+    Padding is exact: extra trees are all pass-through (+big thresholds)
+    with zero leaves — each adds exactly ``(0, 0)``; a depth-``d`` tree
+    padded to ``depth`` walks on through pass-through levels (``x > +big``
+    is never true) to the leftmost descendant, so leaf ``j`` moves to slot
+    ``j << (depth - d)``. +inf thresholds are clipped to a finite big value
+    before the split, which keeps every comparison's outcome.
+    """
+    d = int(model.config.max_depth)
+    f = np.asarray(model.features, np.int32)
+    t, i = f.shape
+    n_int, n_leaf = 2 ** depth - 1, 2 ** depth
+    F = np.zeros((n_trees, n_int), np.int32)
+    TH = np.full((n_trees, n_int), _BIG)
+    LV = np.zeros((n_trees, n_leaf))
+    F[:t, :i] = f
+    TH[:t, :i] = np.clip(np.asarray(model.thresholds, np.float64),
+                         -_BIG, _BIG)
+    LV[:t, ::1 << (depth - d)] = np.asarray(model.leaves, np.float64)
+    thh, thl = dfloat.split(TH.ravel())
+    lvh, lvl = dfloat.split(LV.ravel())
+    return F.ravel(), thh, thl, lvh, lvl
+
+
+def _cfg_row(model, memory_mb: float) -> np.ndarray:
+    row = np.zeros(CFG_WIDTH, np.float32)
+    row[CFG_MEM] = np.float32(memory_mb)
+    row[CFG_LR:CFG_LR + 2] = dfloat.split(float(model.config.learning_rate))
+    row[CFG_BASE:CFG_BASE + 2] = dfloat.split(float(model.base))
+    return row
+
+
+def kernel_operands(model) -> tuple:
+    """Device-ready operands for ``gbrt_predict_blocked``: the flattened
+    ensemble tables (see ``_tree_tables``) and the ``cfg`` row, each with a
+    leading axis of 1, plus ``depth``. Hosted once per model identity
+    (weakref-guarded — refit-by-swap invalidates automatically).
     """
     def build():
-        big = np.float32(3.0e38)
-        thr = np.clip(model.thresholds, -big, big).astype(np.float32)
-        return (jnp.asarray(np.asarray(model.features, np.int32)),
-                jnp.asarray(thr),
-                jnp.asarray(np.asarray(model.leaves, np.float32)))
+        depth = int(model.config.max_depth)
+        tabs = _tree_tables(model, int(np.asarray(model.features).shape[0]),
+                            depth)
+        return tuple(jnp.asarray(a[None, :]) for a in tabs) \
+            + (jnp.asarray(_cfg_row(model, 0.0)[None, :]), depth)
 
     return _cached(_OPERANDS, id(model), (model,), build)
 
 
-def multi_kernel_operands(models) -> tuple:
+def multi_kernel_operands(models, memory_mb) -> tuple:
     """Stacked, padded operands for the blocked ``gbrt_predict_multi`` launch.
 
-    Pads every config's ensemble to the common ``(T, I, L)`` of the deepest /
-    widest one so a single (n_configs, row-blocks) grid covers them all, while
-    staying BIT-IDENTICAL per config to the per-config launches:
+    Pads every config's ensemble to the common ``(T, depth)`` of the
+    largest one (``_tree_tables``), so a single (configs, row-blocks) grid
+    covers them all while staying BIT-IDENTICAL per config to the
+    per-config launch. ``memory_mb[c]`` is config ``c``'s memory feature.
 
-    - extra trees are all-pass-through (+big thresholds) with zero leaves —
-      each contributes exactly ``+0.0f``;
-    - a depth-``d`` tree padded to depth ``dmax`` extends every walk through
-      pass-through levels (``x > +big`` is always false), landing on the
-      leftmost descendant — leaf ``j`` maps to ``j << (dmax - d)``, so leaf
-      values are scattered to those slots and the lookup is exact;
-    - the learning-rate multiply stays in-kernel (per-config ``lr`` operand),
-      preserving the FMA-contracted ``acc + lr * contrib`` accumulation of
-      the per-config kernel bit-for-bit.
-
-    Returns ``(features (C,T,I) i32, thresholds (C,T,I) f32, leaves (C,T,L)
-    f32, lr (C,1) f32, base (C,1) f32, depth)`` with all but ``depth`` as jnp
-    arrays. Cached per model-identity tuple (weakref-guarded, refit-by-swap
-    safe).
+    Returns ``(features, thr_hi, thr_lo, leaf_hi, leaf_lo, cfg, depth)``,
+    all but ``depth`` as jnp arrays with a leading config axis. Cached per
+    (model-identity tuple, memory features) — weakref-guarded, refit-by-swap
+    safe.
     """
     models = tuple(models)
+    mems = tuple(float(m) for m in memory_mb)
 
     def build():
-        big = np.float32(3.0e38)
-        depths = [int(m.config.max_depth) for m in models]
-        dmax = max(depths)
-        tmax = max(int(np.asarray(m.features).shape[0]) for m in models)
-        n_int, n_leaf = 2 ** dmax - 1, 2 ** dmax
-        C = len(models)
-        F = np.zeros((C, tmax, n_int), np.int32)
-        TH = np.full((C, tmax, n_int), big, np.float32)
-        LV = np.zeros((C, tmax, n_leaf), np.float32)
-        LR = np.zeros((C, 1), np.float32)
-        BASE = np.zeros((C, 1), np.float32)
-        for c, m in enumerate(models):
-            f = np.asarray(m.features, np.int32)
-            th = np.clip(m.thresholds, -big, big).astype(np.float32)
-            lv = np.asarray(m.leaves, np.float32)
-            t, i = f.shape
-            F[c, :t, :i] = f
-            TH[c, :t, :i] = th
-            LV[c, :t, ::1 << (dmax - depths[c])] = lv
-            LR[c, 0] = np.float32(m.config.learning_rate)
-            BASE[c, 0] = np.float32(m.base)
-        return (jnp.asarray(F), jnp.asarray(TH), jnp.asarray(LV),
-                jnp.asarray(LR), jnp.asarray(BASE), dmax)
+        depth = max(int(m.config.max_depth) for m in models)
+        n_trees = max(int(np.asarray(m.features).shape[0]) for m in models)
+        tabs = [_tree_tables(m, n_trees, depth) for m in models]
+        stacks = tuple(jnp.asarray(np.stack(col)) for col in zip(*tabs))
+        cfg = np.stack([_cfg_row(m, mem) for m, mem in zip(models, mems)])
+        return stacks + (jnp.asarray(cfg), depth)
 
-    key = tuple(id(m) for m in models)
+    key = (tuple(id(m) for m in models), mems)
     return _cached(_MULTI_OPERANDS, key, models, build)
 
 
-def gbrt_predict(model, x, *, block_n: int = 256,
-                 interpret: bool | None = None) -> np.ndarray:
-    """model: repro.core.gbrt.GBRT; x: (N, F). Returns np.ndarray (N,)."""
+def gbrt_predict(model, x, *, interpret: bool | None = None) -> np.ndarray:
+    """model: repro.core.gbrt.GBRT; x: (N, F). Returns float64 (N,)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    x = np.asarray(x, np.float32)
+        interpret = interpret_mode()
+    x = np.asarray(x, np.float64)
     if x.ndim == 1:
         x = x[:, None]
-    N = x.shape[0]
-    feats, thr, lvs = kernel_operands(model)
-    bn = min(block_n, max(N, 1))
-    pad = (-N) % bn
-    if pad:
-        x = np.pad(x, ((0, pad), (0, 0)))
-    out = gbrt_predict_blocked(
-        jnp.asarray(x), feats, thr, lvs,
-        depth=model.config.max_depth, lr=float(model.config.learning_rate),
-        base=float(model.base), block_n=bn, interpret=interpret)
-    return np.asarray(out)[:N]
+    if x.shape[0] == 0:
+        return np.zeros(0)
+    hi, lo = dfloat.split(x.T)                              # (F, N) each
+    *ops, depth = kernel_operands(model)
+    out_hi, out_lo = gbrt_predict_blocked(
+        jnp.asarray(np.stack([hi, lo], axis=1)), *ops, depth=depth,
+        interpret=interpret)
+    return dfloat.join(out_hi, out_lo)

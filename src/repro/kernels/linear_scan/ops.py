@@ -2,9 +2,9 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.linear_scan.kernel import linear_scan_bsd
 
 
@@ -14,7 +14,7 @@ def linear_scan(x, a, *, chunk: int = 256, interpret: bool | None = None):
     Tail padding uses (a=1, x=0): the state passes through unchanged.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     B, S, D = x.shape
     Q = min(chunk, S)
     pad = (-S) % Q
@@ -24,17 +24,3 @@ def linear_scan(x, a, *, chunk: int = 256, interpret: bool | None = None):
     y, state = linear_scan_bsd(x, a, chunk=Q, interpret=interpret)
     return y[:, :S], state
 
-
-def prefix_sum(delta, *, chunk: int = 256, interpret: bool | None = None):
-    """Inclusive prefix sum of a 1-D sequence via the scan kernel (a ≡ 1).
-
-    ``delta``: (S,). Returns an (S,) fp32 array with ``out[i] = Σ_{j<=i}
-    delta[j]``. A plain running sum is the degenerate RG-LRU recurrence with
-    unit decay, so this routes the surplus-bank prefix of the device
-    placement core (``repro.core.jax_core``, ``SURPLUS_LINEAR_SCAN``) through
-    the same blocked kernel. fp32 accumulation: decision-equality use only.
-    """
-    x = jnp.asarray(delta, jnp.float32)[None, :, None]
-    a = jnp.ones(x.shape, jnp.float32)
-    h, _state = linear_scan(x, a, chunk=chunk, interpret=interpret)
-    return h[0, :, 0]

@@ -2,9 +2,9 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.ssd_scan.kernel import ssd_scan_bhsd
 
 
@@ -16,7 +16,7 @@ def ssd(x, dt, A, B, C, *, chunk: int = 128, interpret: bool | None = None):
     contribution, so the carried state passes through padded steps unchanged.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     b, S, nh, hd = x.shape
     Q = min(chunk, S)
     pad = (-S) % Q

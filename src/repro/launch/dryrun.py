@@ -21,6 +21,8 @@ from repro.launch.mesh import make_production_mesh                      # noqa: 
 from repro.launch.steps import build_cell                               # noqa: E402
 
 OUT_DIR_DEFAULT = "experiments/dryrun"
+# the chip the production mesh models (one v5e pod, ``repro.launch.mesh``)
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
@@ -47,6 +49,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     result = {
         "arch": arch, "shape": shape_name, "kind": cell.kind,
         "mesh": mesh_name, "devices": int(mesh.size), "fsdp": cell.fsdp,
+        "device_kind": TARGET_DEVICE_KIND,
         "param_count": cell.model.param_count(),
         "active_param_count": getattr(cell.model, "active_param_count",
                                       cell.model.param_count)(),

@@ -13,15 +13,22 @@ Meshes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the sharding rules place arrays with with_sharding_constraint,
+    # which rejects the Explicit axes jax.make_mesh now defaults to
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this host has (CPU dev box: 1 device) — smoke tests/examples."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _mesh((n, 1), ("data", "model"))

@@ -402,3 +402,74 @@ def test_resident_state_syncs_for_external_place_many(ir_setup):
     assert_records_equal(res2.records, ref2.records)
     core = jax_core.core_for(rt.engine)
     assert core.chunk_commits >= 1  # the standalone call committed host-side
+
+
+# ------------------------------------------------- the chip's arithmetic
+def _chip_smoke():
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_tpu_branch_decision_identity_past_2_24_ms(monkeypatch):
+    """The TPU branch — two-float, "assoc" scans, the GBRT kernel (here in
+    interpret mode) — steered on a CPU host, through the chip smoke test's
+    own serving body: the paper's 19 configs, an IR stream whose arrivals
+    pass 2**24 ms (where f32 spacing reaches 1-2 ms), decision-identical to
+    the numpy oracle on every record, with every chunk resident."""
+    monkeypatch.setattr(jax_core, "platform", lambda: "tpu")
+    smoke = _chip_smoke()
+    lines = []
+    report = smoke.smoke(n_tasks=4096, chunk=1024, start_ms=1.7e7,
+                         cont_chunks=1, log=lines.append)
+    assert report["minlat"]["n"] == 4096
+    assert report["minlat"]["mismatched"] == 0
+    assert report["mincost"]["mismatched"] == 0
+    assert report["minlat"]["residency"]["resident_chunks"] == 4
+    for err in report["minlat"]["max_rel_err"].values():
+        assert err < 1e-12
+    assert lines  # the smoke's progress lines went to the given log
+
+
+def test_core_refuses_to_hide_the_device(ir_setup, monkeypatch):
+    """A core that cannot build for a non-semantic reason raises instead of
+    serving on numpy: here the GBRT kernel asked for on an accelerator its
+    Mosaic code cannot run on."""
+    import jax
+
+    from repro.core import predictor as predictor_mod
+
+    twin, models = ir_setup
+    monkeypatch.setattr(predictor_mod, "GBRT_KERNEL_MODE", "force")
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    rt = _runtime(twin, models)
+    with pytest.raises(RuntimeError, match="cannot run on a 'gpu'"):
+        rt.serve_stream(_bursty(twin, 16), chunk_size=16,
+                        array_backend="jax")
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set; without
+    it the cache sits at the fixed ``<checkout>/.jax_cache``."""
+    import jax
+
+    from repro import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+        assert compile_cache.configure() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv(compile_cache.ENV)
+        where = compile_cache.configure()
+        assert where == str(compile_cache.DEFAULT_DIR)
+        assert where.endswith(".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == where
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -151,7 +151,7 @@ def test_gbrt_predict_sweep(n_features, depth, n_trees, rng):
     y = x[:, 0] * 2.0 + np.sin(x[:, -1] / 30.0) * 10.0 + rng.normal(size=400)
     m = GBRT.fit(x, y, GBRTConfig(n_trees=n_trees, max_depth=depth))
     xq = rng.normal(size=(137, n_features)) * 100.0
-    pk = gbrt_predict(m, xq, block_n=64)
+    pk = gbrt_predict(m, xq)
     pr = gbrt_predict_ref(xq.astype(np.float32), m.features, m.thresholds,
                           m.leaves, depth=depth,
                           lr=m.config.learning_rate, base=m.base)
@@ -162,8 +162,10 @@ def test_gbrt_predict_sweep(n_features, depth, n_trees, rng):
 
 def test_gbrt_predict_multi_matches_per_config(rng):
     """The blocked multi-config launch (one grid over the padded operand
-    stack) is BIT-identical per column to a per-config launch — including
-    heterogeneous depths/tree counts and a repeated model (shared id)."""
+    stack) is BIT-identical per config to a per-config launch — including
+    heterogeneous depths/tree counts and a repeated model (shared id) — and
+    its two-float result agrees with the float64 tree walk."""
+    from repro.kernels import dfloat
     from repro.kernels.gbrt_predict.kernel import (
         gbrt_predict_blocked,
         gbrt_predict_multi,
@@ -181,22 +183,24 @@ def test_gbrt_predict_multi_matches_per_config(rng):
                                                 max_depth=depth)))
     models.append(models[0])  # same model under two configs
     mems = [1280.0, 1536.0, 1792.0, 2048.0]
-    sizes = (rng.normal(size=(256,)) * 100.0).astype(np.float32)
+    sizes = rng.normal(size=(256,)) * 100.0
 
-    F, TH, LV, LR, BASE, dmax = multi_kernel_operands(models)
-    MEM = jnp.asarray(np.array([[m] for m in mems], np.float32))
-    multi = np.asarray(gbrt_predict_multi(
-        jnp.asarray(sizes[:, None]), MEM, LR, BASE, F, TH, LV,
-        depth=dmax, block_n=64, interpret=True))
-    assert multi.shape == (256, len(models))
+    *ops, dmax = multi_kernel_operands(models, mems)
+    hi, lo = dfloat.split(sizes)
+    mh, ml = gbrt_predict_multi(jnp.asarray(np.stack([hi, lo])), *ops,
+                                depth=dmax, interpret=True)
+    assert mh.shape == (len(models), 256)
     for c, (m, mem) in enumerate(zip(models, mems)):
-        feats, thr, lvs = kernel_operands(m)
-        x2 = np.stack([sizes, np.full(256, mem, np.float32)], axis=1)
-        single = np.asarray(gbrt_predict_blocked(
-            jnp.asarray(x2), feats, thr, lvs, depth=m.config.max_depth,
-            lr=float(m.config.learning_rate), base=float(m.base),
-            block_n=64, interpret=True))
-        assert np.array_equal(multi[:, c], single), f"config {c}"
+        *single_ops, depth = kernel_operands(m)
+        mem_hi, mem_lo = dfloat.split(np.full(256, mem))
+        x2 = np.stack([np.stack([hi, lo]), np.stack([mem_hi, mem_lo])])
+        sh, sl = gbrt_predict_blocked(jnp.asarray(x2), *single_ops,
+                                      depth=depth, interpret=True)
+        assert np.array_equal(np.asarray(mh[c]), np.asarray(sh)), c
+        assert np.array_equal(np.asarray(ml[c]), np.asarray(sl)), c
+        ref = m.predict(np.stack([sizes, np.full(256, mem)], axis=1))
+        np.testing.assert_allclose(dfloat.join(sh, sl), ref, rtol=1e-12,
+                                   atol=1e-9)
 
 
 def test_gbrt_operand_caches(rng):
@@ -213,8 +217,8 @@ def test_gbrt_operand_caches(rng):
     m1 = GBRT.fit(x, y, GBRTConfig(n_trees=8, max_depth=2))
     ops1 = kernel_operands(m1)
     assert kernel_operands(m1) is ops1
-    multi1 = multi_kernel_operands((m1, m1))
-    assert multi_kernel_operands((m1, m1)) is multi1
+    multi1 = multi_kernel_operands((m1, m1), (1280.0, 1536.0))
+    assert multi_kernel_operands((m1, m1), (1280.0, 1536.0)) is multi1
     m2 = GBRT.fit(x, y, GBRTConfig(n_trees=8, max_depth=2))  # "refit"
     assert kernel_operands(m2) is not ops1
-    assert multi_kernel_operands((m1, m2)) is not multi1
+    assert multi_kernel_operands((m1, m2), (1280.0, 1536.0)) is not multi1

@@ -144,3 +144,22 @@ def test_cp_attention_grad_matches(rng):
                                                  ways=2).sum())(q)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-4,
                                atol=1e-5)
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the table for the device a cell was compiled for;
+    a device without published peaks is an error, never a default."""
+    roofline = pytest.importorskip("benchmarks.roofline")
+    v5e = "TPU v5 lite"  # jax's device_kind of a v5e chip
+
+    assert roofline.peaks(v5e)["flops"] == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks("TPU v9 imaginary")
+    cell = {"hlo": {"flops": 197e12, "hbm_bytes": 0.0,
+                    "collective_link_bytes": 0.0},
+            "kind": "train", "devices": 1, "param_count": 1,
+            "shape": "train_4k", "arch": "a", "mesh": "pod"}
+    with pytest.raises(ValueError):
+        roofline.analyze_cell(cell)
+    out = roofline.analyze_cell({**cell, "device_kind": v5e})
+    assert out["dominant"] == "compute" and out["compute_s"] == 1.0

@@ -482,6 +482,33 @@ def test_sharded_process_mode_equals_sequential(app_setups):
                              seq.results[app].records)
 
 
+def _jax_shard_runtime(app, setups):
+    rt = _shard_runtime(app, setups)
+    rt.engine.array_backend = "jax"
+    return rt
+
+
+def test_sharded_process_children_never_touch_jax(app_setups, monkeypatch):
+    """Spawned children are numpy-only by construction: with JAX unable to
+    initialize any backend in them, process mode still serves, per record
+    identical to the sequential run — and a shard asking for the device
+    core is refused instead of reaching for a chip the parent may hold."""
+    shards = _make_shards(app_setups, n=64)
+    seq = ShardedRuntime(shards).serve(parallel=False)
+    monkeypatch.setenv("JAX_PLATFORMS", "no_such_platform")  # children only
+    proc = ShardedRuntime(shards).serve(parallel=True, use_processes=True)
+    for app in app_setups:
+        assert_records_equal(proc.results[app].records,
+                             seq.results[app].records)
+    shard = AppShard(name="IR",
+                     runtime=functools.partial(_jax_shard_runtime, "IR",
+                                               app_setups),
+                     workload=functools.partial(_shard_workload, "IR",
+                                                app_setups, 16))
+    with pytest.raises(ValueError, match="numpy"):
+        ShardedRuntime([shard]).serve(parallel=True, use_processes=True)
+
+
 def test_sharded_process_mode_requires_factories(app_setups):
     rt = _shard_runtime("IR", app_setups)
     shard = AppShard(name="IR", runtime=rt, workload=[])
