@@ -1,0 +1,7 @@
+"""Device time of the place program per 1000 tasks, over the traced chunks."""
+
+from harness import layers
+
+
+def read(ctx):
+    return layers.program_ms_per_ktask(ctx, "place")

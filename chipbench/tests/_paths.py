@@ -1,0 +1,10 @@
+"""Puts the benchmark's directory and the program's ``src`` on the path."""
+
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+ROOT = BENCH.parent
+for p in (str(ROOT / "src"), str(BENCH)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
