@@ -595,7 +595,11 @@ class DecisionEngine:
                 edge_queues = {n: PredictedEdgeQueue() for n in names}
         # device-resident route, BEFORE the (expensive) host prediction pass
         # it exists to avoid; record_decisions stays on the numpy path (its
-        # views would rebuild the prediction batch anyway)
+        # views would rebuild the prediction batch anyway). ``jax_stats``
+        # describes the last chunk the core placed: a chunk it does not
+        # place leaves none.
+        if self.array_backend != "numpy":
+            self.__dict__.pop("jax_stats", None)
         if tasks and self.columnar and self.array_backend != "numpy" \
                 and not self.record_decisions and self._columnar_eligible():
             from repro.core import jax_core

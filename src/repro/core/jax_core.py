@@ -507,6 +507,7 @@ class JaxPlacementCore:
         self.resident_chunks = 0  # chunks absorbed without a host sync
         self.chunk_commits = 0    # legacy per-chunk host commits
         self.resident_regrows = 0  # donated-seed restore+retry events
+        self.d2h_reads = 0        # device arrays place_chunk read back
 
     # ------------------------------------------------------------ lifecycle
     def _scope(self):
@@ -519,11 +520,23 @@ class JaxPlacementCore:
                 and all(r() is not None for r in self._refs))
 
     def compile_stats(self) -> dict:
-        """jit-cache sizes — the bench's no-retrace probe."""
-        return {"predict": self._predict._cache_size(),
-                "place": self._place._cache_size(),
-                "state": self._state._cache_size(),
-                "choose": self._choose._cache_size()}
+        """jit-cache sizes of every program — the no-retrace probe."""
+        fns = {"predict": self._predict, "place": self._place,
+               "state": self._state, "choose": self._choose,
+               "finalize": self._finalize, "compact": self._compact,
+               "shift": self._shift}
+        return {k: f._cache_size() if f is not None else 0
+                for k, f in fns.items()}
+
+    def _read(self, x) -> np.ndarray:
+        """One device array read back to the host (``d2h_reads``)."""
+        self.d2h_reads += 1
+        return np.asarray(x)
+
+    def _read_f(self, x) -> np.ndarray:
+        """A float value read back: a two-float pair is two arrays."""
+        self.d2h_reads += 2 if self.A.df else 1
+        return self.A.host(x)
 
     def _base(self, nows_np) -> float:
         """Host time origin of one chunk's device times: its first arrival
@@ -1257,6 +1270,7 @@ class JaxPlacementCore:
 
         jax, jnp, A = self.jax, self.jnp, self.A
         n = len(tasks)
+        spans = engine.__dict__.get("_spans")   # serve_stream's recorder
         staged = engine.__dict__.pop("_jax_staged", None)
         if staged is not None and staged[0] is not tasks:
             staged = None       # stale prefetch for some other chunk
@@ -1354,6 +1368,8 @@ class JaxPlacementCore:
                 P["deadline"] = A.const(policy.deadline_ms)
             res = None
             compacted = rs is None   # host seeds arrive freshly reaped
+            if spans is not None:
+                spans.switch("place")
             while True:
                 if cap < max_existing + 1:
                     cap = _next_pow2(max_existing + 1)
@@ -1367,7 +1383,8 @@ class JaxPlacementCore:
                     backup = (jax.tree.map(jnp.copy, S)
                               if rs is not None else None)
                     res = self._place(P, S)
-                if not bool(res["overflow"]) and bool(res["converged"]):
+                if not bool(self._read(res["overflow"])) \
+                        and bool(self._read(res["converged"])):
                     break
                 # pool too small for this chunk's cold starts (clamped
                 # writes may also stall convergence): results are discarded
@@ -1393,7 +1410,7 @@ class JaxPlacementCore:
                         rs.busy, rs.last, rs.cnt = self._compact(
                             rs.busy, rs.last, rs.cnt,
                             A.const(rs.t_last - rs.base))
-                        rs.cnt_max = int(np.asarray(rs.cnt).max())
+                        rs.cnt_max = int(self._read(rs.cnt).max())
                         max_existing = rs.cnt_max
                         compacted = True
                         continue
@@ -1406,12 +1423,14 @@ class JaxPlacementCore:
                         "overflow-proof container pool")
                 cap = new_cap
             self._cap_hint = cap
+            if spans is not None:
+                spans.switch("d2h")
 
-            out = {k: A.host(res[k])[:n] for k in
+            out = {k: self._read_f(res[k])[:n] for k in
                    ("lat", "cost", "comp", "wait", "allowed")}
-            out.update({k: np.asarray(res[k])[:n] for k in
+            out.update({k: self._read(res[k])[:n] for k in
                         ("gcode", "cold", "feas")})
-            iters = int(res["iters"])
+            iters = int(self._read(res["iters"]))
             t_last = float(nows_np[-1])
             if residency:
                 # ---- stay resident: committed state LIVES on device -------
@@ -1420,7 +1439,7 @@ class JaxPlacementCore:
                 if self.n_cloud:
                     rs.busy, rs.last, rs.cnt = \
                         res["busyF"], res["lastF"], res["cntF"]
-                    rs.cnt_max = int(res["cnt_max"])
+                    rs.cnt_max = int(self._read(res["cnt_max"]))
                 if self.has_edge:
                     rs.h = res["h_fin"]
                 if self.is_minlat:
@@ -1435,29 +1454,26 @@ class JaxPlacementCore:
             else:
                 # ---- commit host state (the numpy accept step, once) ------
                 if self.is_minlat:
-                    policy.surplus = float(A.host(res["s_fin"]))
+                    policy.surplus = float(self._read_f(res["s_fin"]))
                 if self.has_edge:
-                    h_fin = A.host(res["h_fin"]) + base
+                    h_fin = self._read_f(res["h_fin"]) + base
                     for d, nm in enumerate(dev_names):
                         edge_queues[nm].horizon_ms = float(h_fin[d])
                 if self.n_cloud:
-                    self._commit_pools(cil, A.host(res["busyF"]) + base,
-                                       A.host(res["lastF"]) + base,
-                                       np.asarray(res["cntF"]), t_last)
+                    self._commit_pools(cil, self._read_f(res["busyF"]) + base,
+                                       self._read_f(res["lastF"]) + base,
+                                       self._read(res["cntF"]), t_last)
                 self.chunk_commits += 1
 
         nom_out = None
         if self.has_edge:
-            nom_out = np.asarray(res["nom"])[:n].astype(np.int64)
+            nom_out = self._read(res["nom"])[:n].astype(np.int64)
         engine.columnar_stats = {"chunks": 1, "repairs": max(iters - 1, 0),
                                  "walked": 0, "n": n}
         self.last_stats = {"n": n, "passes": iters + 1, "rows": R,
                            "pool_cap": cap, "interpret": interpret,
-                           "two_float": A.df,
                            "gbrt_kernel": self.use_gbrt_kernel,
-                           "kernel_interpret": self.kernel_interpret,
-                           "resident": residency,
-                           "staged": staged is not None}
+                           "resident": residency}
         engine.jax_stats = dict(self.last_stats)
         return DecisionBatch(
             batch=None,

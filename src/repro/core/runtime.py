@@ -58,6 +58,7 @@ started (live).
 
 from __future__ import annotations
 
+import time
 import zlib
 from dataclasses import dataclass
 from typing import Protocol, Sequence, runtime_checkable
@@ -106,6 +107,7 @@ from repro.core.predictor import Prediction
 from repro.core.pricing import LambdaPricing
 from repro.core.records import RecordArena, RecordBatch, SimulationResult, TaskRecord
 from repro.core.recurrence import fifo_starts
+from repro.core.spans import Spans
 from repro.core.workload import TaskChunk, TaskInput, task_arrays, task_tiers
 
 
@@ -333,6 +335,10 @@ class TwinBackend:
             for n in names}
         # per-device edge executor state (single-slot FIFO)
         self.edge_free_at = {n: 0.0 for n in names}
+        # container slots ``execute_many``'s pool walk has visited: the
+        # per-dispatch scan, the reap's rebuild, and the per-call rebuild of
+        # each touched config's container list
+        self.twin_slots = 0
 
     @property
     def edge_free_at_actual(self) -> float:
@@ -607,6 +613,7 @@ class TwinBackend:
             start_l = [0.0] * nc
             was_cold = [False] * nc
             pools = self.gt_cloud.pools
+            slots = 0
             by_cfg: dict[str, list[int]] = {}
             for j, cfg in enumerate(cfgs):
                 lst = by_cfg.get(cfg)
@@ -623,6 +630,7 @@ class TwinBackend:
                     best = -1
                     best_last = -1e308
                     reap = False
+                    slots += len(busy_l)
                     for i in range(len(busy_l)):
                         if busy_l[i] <= t:
                             if t <= exp_l[i]:
@@ -633,6 +641,7 @@ class TwinBackend:
                             else:
                                 reap = True  # expired idle container
                     if reap:  # rare (27-min lifetimes): rebuild only when needed
+                        slots += len(busy_l)
                         nb: list[float] = []
                         nl: list[float] = []
                         ne: list[float] = []
@@ -664,6 +673,8 @@ class TwinBackend:
                     start_l[j] = st
                 pools[cfg] = [GTContainer(b, li, e)
                               for b, li, e in zip(busy_l, last_l, exp_l)]
+                slots += len(busy_l)
+            self.twin_slots += slots
             start = np.asarray(start_l)
             latency = upld + start + comp + store
             if faults is not None:
@@ -907,13 +918,22 @@ def _iter_chunks(workload, chunk_size: int):
     yield from it
 
 
+def _no_lap(name: str) -> None:
+    """The span switch of a stream that records no spans (numpy)."""
+
+
+# the core's monotone counters a stream reports as its own differences
+_CORE_COUNTERS = ("state_syncs", "fallback_syncs", "resident_chunks",
+                  "chunk_commits", "resident_regrows", "d2h_reads")
+
+
 def _engine_core(eng):
     """The engine's cached jax placement core, or None (never builds one)."""
     hit = eng.__dict__.get("_jax_core_cache")
     return hit[1] if hit is not None else None
 
 
-def _prefetched_chunks(it, eng, counters: dict):
+def _prefetched_chunks(it, eng, counters: dict, spans):
     """Double-buffered chunk staging for a device-backed ``serve_stream``.
 
     A single transfer thread pulls chunk k+1 from the workload iterator AND
@@ -925,29 +945,39 @@ def _prefetched_chunks(it, eng, counters: dict):
     the dict is never raced) and validated by chunk identity; a chunk that
     ends up on a fallback path simply leaves its bundle to be discarded.
     ``stage_chunk`` is engine-state-free, so staging never observes a
-    half-updated stream.
+    half-updated stream. The chunk's ``stage`` and ``ready_wait`` spans
+    (``repro.core.spans``) are added on the consumer thread when it takes
+    the chunk.
     """
     from concurrent.futures import ThreadPoolExecutor
 
-    def pull():
+    def pull(seq):
         chunk = next(it, None)
         if chunk is None:
             return None
         staged = None
+        t0 = time.perf_counter()
         if len(chunk):
             core = _engine_core(eng)  # appears once the first chunk compiled
             if core is not None:
-                staged = core.stage_chunk(chunk)
-        return chunk, staged
+                with spans.annotate("stage", chunk=seq):
+                    staged = core.stage_chunk(chunk)
+        t1 = time.perf_counter()
+        return chunk, staged, t1 - t0, t1
 
+    seq = 0   # sequence number of the next non-empty chunk
     with ThreadPoolExecutor(max_workers=1) as ex:
-        fut = ex.submit(pull)
+        fut = ex.submit(pull, seq)
         while True:
             item = fut.result()
+            taken = time.perf_counter()
             if item is None:
                 return
-            fut = ex.submit(pull)
-            chunk, staged = item
+            chunk, staged, stage_s, ready = item
+            seq += len(chunk) > 0
+            fut = ex.submit(pull, seq)
+            spans.totals["stage"] += stage_s
+            spans.totals["ready_wait"] += taken - ready
             if staged is not None:
                 eng.__dict__["_jax_staged"] = (chunk, staged)
                 counters["prefetched"] += 1
@@ -1104,8 +1134,18 @@ class PlacementRuntime:
           transfer with device compute.
 
         ``stream_stats["residency"]`` afterwards reports the resident-chunk
-        / sync / prefetch counters for this stream, and ``fallback_chunks``:
-        chunks the core refused on semantic grounds and numpy served.
+        / sync / regrow / prefetch counters for this stream, and
+        ``fallback_chunks``: chunks the core refused on semantic grounds and
+        numpy served.
+
+        A jax-backed stream also records per-chunk spans of its loop
+        (``repro.core.spans``) and three counters: ``d2h_reads`` (device
+        arrays the core read back), ``twin_slots`` (container slots the
+        twin's pool walk visited) and ``resident_regrows``. Their stream
+        totals join ``engine.jax_stats`` before each backend call of a
+        chunk the core placed (so the difference of two consecutive
+        readings is one loop cycle's), and land in ``stream_stats["spans"]``
+        at the stream's end.
         """
         if chunk_size < 1:
             raise ValueError(f"chunk_size must be >= 1, got {chunk_size}")
@@ -1134,21 +1174,25 @@ class PlacementRuntime:
                        and not eng.record_decisions)
         pf = {"prefetched": 0}
         base: dict = {}
+        spans = None
+        lap = _no_lap
         if use_device:
             c0 = _engine_core(eng)
             if c0 is not None:
-                base = {"state_syncs": c0.state_syncs,
-                        "fallback_syncs": c0.fallback_syncs,
-                        "resident_chunks": c0.resident_chunks,
-                        "chunk_commits": c0.chunk_commits}
+                base = {k: getattr(c0, k) for k in _CORE_COUNTERS}
+            base["twin_slots"] = getattr(self.backend, "twin_slots", 0)
             if residency:
                 eng.__dict__["_device_residency"] = True
+            spans = Spans()
+            lap = spans.switch
+            eng.__dict__["_spans"] = spans
         chunk_iter = _iter_chunks(workload, chunk_size)
         if do_prefetch:
-            chunk_iter = _prefetched_chunks(chunk_iter, eng, pf)
+            chunk_iter = _prefetched_chunks(chunk_iter, eng, pf, spans)
         prev_last = -np.inf
         force_walk = False
         try:
+            lap("fetch_wait")
             for chunk in chunk_iter:
                 m = len(chunk)
                 if m == 0:
@@ -1168,13 +1212,22 @@ class PlacementRuntime:
                 try:
                     if force_walk:
                         eng.columnar = False
+                    lap("pre_place")
                     self._pre_place(chunk)
                     self._snapshot_horizons()
+                    lap("predict")
                     decisions = eng.place_many(
                         chunk, edge_queues=self.edge_queues)
                 finally:
                     eng.columnar = was_columnar
+                lap("execute")
+                js = eng.__dict__.get("jax_stats")
+                if spans is not None and js is not None:
+                    # the core placed this chunk: its record gains the
+                    # stream's totals so far, read at the backend call
+                    js.update(self._span_totals(spans, base))
                 recs = self._execute_decisions(chunk, decisions)
+                lap("tail")
                 arena.append(recs)
                 self._post_execute(recs)
                 stats["chunks"] += 1
@@ -1186,9 +1239,14 @@ class PlacementRuntime:
                     stats["walked"] += cs["walked"]
                 else:
                     stats["walked"] += m
+                if spans is not None:
+                    spans.chunk += 1
+                lap("fetch_wait")
         finally:
             eng.array_backend = was_backend
             if use_device:
+                spans.stop()
+                eng.__dict__.pop("_spans", None)
                 eng.__dict__.pop("_device_residency", None)
                 eng.__dict__.pop("_jax_staged", None)
                 core = _engine_core(eng)
@@ -1199,15 +1257,32 @@ class PlacementRuntime:
             if core is not None:
                 r = {k: getattr(core, k) - base.get(k, 0)
                      for k in ("resident_chunks", "state_syncs",
-                               "fallback_syncs", "chunk_commits")}
+                               "fallback_syncs", "chunk_commits",
+                               "resident_regrows")}
                 # chunks served on numpy by a semantic refusal (hedged or
                 # custom policy, out-of-order arrivals, ...)
                 r["fallback_chunks"] = stats["chunks"] \
                     - r["resident_chunks"] - r["chunk_commits"]
                 stats["residency"] = {"enabled": residency, **r,
                                       "prefetched": pf["prefetched"]}
+            stats["spans"] = self._span_totals(spans, base)
+            js = eng.__dict__.get("jax_stats")
+            if js is not None and stats["chunks"]:
+                js.update(stats["spans"])
         self.stream_stats = stats
         return self.result(arena.finish())
+
+    def _span_totals(self, spans: Spans, base: dict) -> dict:
+        """The stream's span seconds and counters so far: flat, monotone
+        totals (counters as differences from the stream's start)."""
+        out = dict(spans.totals)
+        core = _engine_core(self.engine)
+        for k in ("d2h_reads", "resident_regrows"):
+            out[k] = (getattr(core, k) if core is not None else 0) \
+                - base.get(k, 0)
+        out["twin_slots"] = getattr(self.backend, "twin_slots", 0) \
+            - base["twin_slots"]
+        return out
 
     def serve_async(self, tasks: list[TaskInput]) -> SimulationResult:
         """The event-driven serve: place like ``serve(batched=True)``, then
@@ -1356,6 +1431,9 @@ class PlacementRuntime:
                     tasks, decisions
                     if getattr(self.backend, "accepts_decision_batch", False)
                     else decisions.target_list())
+                spans = self.engine.__dict__.get("_spans")
+                if spans is not None:
+                    spans.switch("tail")   # record assembly is not the twin
                 if isinstance(eb, ExecutionBatch):
                     return self._record_batch(tasks, decisions, eb)
                 return [self._record(t, d, d.target, d.prediction, o)
