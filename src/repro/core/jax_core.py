@@ -47,9 +47,10 @@ boundaries stop being host↔device sync points):
    time and dead containers are never warm-reusable, so the one deferred
    reap drops exactly the records the per-chunk reaps would have (order
    preserved — slot order is list order in both). ``stage_chunk`` +
-   ``runtime._prefetched_chunks`` double-buffer the NEXT chunk's task arrays
+   ``runtime._Prefetcher`` double-buffer the NEXT chunk's task arrays
    onto the device (``jax.device_put`` on a transfer thread) while the
-   current fixed point runs, and the GBRT compute column launches ONE
+   current chunk is placed (backlogged source) or executed (caught-up
+   source), and the GBRT compute column launches ONE
    blocked multi-config Pallas kernel (``gbrt_predict_multi``) instead of a
    launch per cloud config.
 
@@ -1148,8 +1149,8 @@ class JaxPlacementCore:
 
     def stage_chunk(self, tasks) -> dict:
         """Host prep + device upload for one chunk — engine-state-free, so
-        ``runtime._prefetched_chunks`` can run it on the transfer thread
-        while the previous chunk's fixed point occupies the device (the x64
+        ``runtime._Prefetcher`` can run it on the transfer thread while
+        the loop works on the previous chunk (the x64
         scope is thread-local and re-entered here). The bundle reaches
         ``place_chunk`` via ``engine._jax_staged``."""
         host = task_arrays(tasks)

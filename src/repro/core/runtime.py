@@ -933,55 +933,104 @@ def _engine_core(eng):
     return hit[1] if hit is not None else None
 
 
-def _prefetched_chunks(it, eng, counters: dict, spans):
+class _Prefetcher:
     """Double-buffered chunk staging for a device-backed ``serve_stream``.
 
-    A single transfer thread pulls chunk k+1 from the workload iterator AND
-    uploads its padded task arrays (``jax_core.stage_chunk`` →
-    ``jax.device_put``) while the consumer runs chunk k's fixed point on
-    device — overlapping workload generation and the H2D transfer with
-    compute. The staged bundle is handed to ``place_chunk`` through
-    ``eng._jax_staged`` (set here on the CONSUMER thread at yield time, so
-    the dict is never raced) and validated by chunk identity; a chunk that
-    ends up on a fallback path simply leaves its bundle to be discarded.
+    A single transfer thread pulls the next chunk from the workload iterator
+    AND uploads its padded task arrays (``jax_core.stage_chunk`` →
+    ``jax.device_put``) while the loop works on the chunk before it. When
+    that pull starts follows the chunk the loop has just taken:
+
+    - a full chunk (``chunk_size`` tasks, or an empty one) says the source
+      is backlogged: the next pull is submitted at once and overlaps
+      workload generation and the H2D transfer with the chunk's placement;
+    - a short chunk says the source is caught up: it handed back all it
+      had. Pulled at once, the next chunk would be frozen now and sit
+      staged through the whole placement, while the tasks that come due
+      meanwhile wait for the chunk after it. The pull is held until the
+      loop hands the chunk to the backend (``release``), the last point
+      where staging still overlaps work (the backend call and the loop's
+      tail): placing the next chunk reads predicted state only, never this
+      chunk's outcomes, so no work moves.
+
+    A held pull is a pull not yet submitted: a loop that asks for the next
+    chunk without a backend call in between submits it then, and closing
+    the stream drops it, so nothing ever waits on it.
+
+    The staged bundle is handed to ``place_chunk`` through
+    ``eng._jax_staged`` (set on the CONSUMER thread at yield time, so the
+    dict is never raced) and validated by chunk identity; a chunk that ends
+    up on a fallback path simply leaves its bundle to be discarded.
     ``stage_chunk`` is engine-state-free, so staging never observes a
     half-updated stream. The chunk's ``stage`` and ``ready_wait`` spans
     (``repro.core.spans``) are added on the consumer thread when it takes
     the chunk.
     """
-    from concurrent.futures import ThreadPoolExecutor
 
-    def pull(seq):
-        chunk = next(it, None)
+    def __init__(self, it, eng, spans, chunk_size: int):
+        self.it, self.eng, self.spans = it, eng, spans
+        self.chunk_size = chunk_size
+        self.prefetched = 0   # chunks handed over with their arrays staged
+        self.late_pulls = 0   # chunks whose pull was held for a backend call
+        self._ex = None       # the transfer thread, while iterating
+        self._pull = None     # the future of the pull in flight
+        self._held = None     # sequence number of the held pull
+
+    def _pull_chunk(self, seq):
+        """On the transfer thread: the next chunk and its staged arrays."""
+        chunk = next(self.it, None)
         if chunk is None:
             return None
         staged = None
         t0 = time.perf_counter()
         if len(chunk):
-            core = _engine_core(eng)  # appears once the first chunk compiled
-            if core is not None:
-                with spans.annotate("stage", chunk=seq):
+            core = _engine_core(self.eng)  # appears once the first chunk
+            if core is not None:           # compiled
+                with self.spans.annotate("stage", chunk=seq):
                     staged = core.stage_chunk(chunk)
         t1 = time.perf_counter()
         return chunk, staged, t1 - t0, t1
 
-    seq = 0   # sequence number of the next non-empty chunk
-    with ThreadPoolExecutor(max_workers=1) as ex:
-        fut = ex.submit(pull, seq)
-        while True:
-            item = fut.result()
-            taken = time.perf_counter()
-            if item is None:
-                return
-            chunk, staged, stage_s, ready = item
-            seq += len(chunk) > 0
-            fut = ex.submit(pull, seq)
-            spans.totals["stage"] += stage_s
-            spans.totals["ready_wait"] += taken - ready
-            if staged is not None:
-                eng.__dict__["_jax_staged"] = (chunk, staged)
-                counters["prefetched"] += 1
-            yield chunk
+    def _submit(self, seq) -> None:
+        self._pull = self._ex.submit(self._pull_chunk, seq)
+
+    def release(self) -> None:
+        """The loop is handing its chunk to the backend: submit the held
+        pull, if any."""
+        if self._held is not None:
+            self._submit(self._held)
+            self._held = None
+
+    def __iter__(self):
+        from concurrent.futures import ThreadPoolExecutor
+
+        seq = 0        # sequence number of the next non-empty chunk
+        short = False  # the chunk taken last was short
+        with ThreadPoolExecutor(max_workers=1) as self._ex:
+            self._submit(seq)
+            try:
+                while True:
+                    self.release()
+                    item = self._pull.result()
+                    taken = time.perf_counter()
+                    if item is None:
+                        return
+                    chunk, staged, stage_s, ready = item
+                    self.late_pulls += short
+                    seq += len(chunk) > 0
+                    short = 0 < len(chunk) < self.chunk_size
+                    if short:
+                        self._held = seq
+                    else:
+                        self._submit(seq)
+                    self.spans.totals["stage"] += stage_s
+                    self.spans.totals["ready_wait"] += taken - ready
+                    if staged is not None:
+                        self.eng.__dict__["_jax_staged"] = (chunk, staged)
+                        self.prefetched += 1
+                    yield chunk
+            finally:
+                self._held = None
 
 
 # -------------------------------------------------------------- the runtime
@@ -1129,19 +1178,29 @@ class PlacementRuntime:
           configured (those read/mutate host placement state mid-stream).
         - ``prefetch`` (default on) double-buffers chunk staging: a
           transfer thread pulls chunk k+1 from the workload iterator and
-          uploads its task arrays (``jax.device_put``) while chunk k's
-          fixed point runs, overlapping workload generation and H2D
-          transfer with device compute.
+          uploads its task arrays (``jax.device_put``) while the loop works
+          on chunk k. When chunk k filled ``chunk_size`` the source is
+          backlogged, and the pull starts as the loop takes chunk k,
+          overlapping workload generation and H2D transfer with chunk k's
+          fixed point. When chunk k came short the source is caught up (a
+          live feed handing back every task due so far): an early pull
+          would freeze chunk k+1 at that moment and leave it staged for the
+          whole placement of chunk k, so the pull is held until chunk k goes
+          to the backend, and staging overlaps the backend call instead.
+          Decisions are the same either way; only where chunk boundaries
+          fall in a caught-up stream moves.
 
         ``stream_stats["residency"]`` afterwards reports the resident-chunk
-        / sync / regrow / prefetch counters for this stream, and
+        / sync / regrow / prefetch counters for this stream (``late_pulls``:
+        chunks whose pull was held for the backend call), and
         ``fallback_chunks``: chunks the core refused on semantic grounds and
         numpy served.
 
         A jax-backed stream also records per-chunk spans of its loop
-        (``repro.core.spans``) and three counters: ``d2h_reads`` (device
+        (``repro.core.spans``) and four counters: ``d2h_reads`` (device
         arrays the core read back), ``twin_slots`` (container slots the
-        twin's pool walk visited) and ``resident_regrows``. Their stream
+        twin's pool walk visited), ``resident_regrows`` and
+        ``late_pulls``. Their stream
         totals join ``engine.jax_stats`` before each backend call of a
         chunk the core placed (so the difference of two consecutive
         readings is one loop cycle's), and land in ``stream_stats["spans"]``
@@ -1172,7 +1231,7 @@ class PlacementRuntime:
         do_prefetch = (use_device
                        and (prefetch is None or prefetch)
                        and not eng.record_decisions)
-        pf = {"prefetched": 0}
+        pf = None
         base: dict = {}
         spans = None
         lap = _no_lap
@@ -1188,7 +1247,8 @@ class PlacementRuntime:
             eng.__dict__["_spans"] = spans
         chunk_iter = _iter_chunks(workload, chunk_size)
         if do_prefetch:
-            chunk_iter = _prefetched_chunks(chunk_iter, eng, pf, spans)
+            pf = _Prefetcher(chunk_iter, eng, spans, chunk_size)
+            chunk_iter = iter(pf)
         prev_last = -np.inf
         force_walk = False
         try:
@@ -1225,7 +1285,9 @@ class PlacementRuntime:
                 if spans is not None and js is not None:
                     # the core placed this chunk: its record gains the
                     # stream's totals so far, read at the backend call
-                    js.update(self._span_totals(spans, base))
+                    js.update(self._span_totals(spans, base, pf))
+                if pf is not None:
+                    pf.release()
                 recs = self._execute_decisions(chunk, decisions)
                 lap("tail")
                 arena.append(recs)
@@ -1243,6 +1305,8 @@ class PlacementRuntime:
                     spans.chunk += 1
                 lap("fetch_wait")
         finally:
+            if pf is not None:
+                chunk_iter.close()   # joins the transfer thread
             eng.array_backend = was_backend
             if use_device:
                 spans.stop()
@@ -1263,19 +1327,23 @@ class PlacementRuntime:
                 # custom policy, out-of-order arrivals, ...)
                 r["fallback_chunks"] = stats["chunks"] \
                     - r["resident_chunks"] - r["chunk_commits"]
-                stats["residency"] = {"enabled": residency, **r,
-                                      "prefetched": pf["prefetched"]}
-            stats["spans"] = self._span_totals(spans, base)
+                stats["residency"] = {
+                    "enabled": residency, **r,
+                    "prefetched": pf.prefetched if pf is not None else 0,
+                    "late_pulls": pf.late_pulls if pf is not None else 0}
+            stats["spans"] = self._span_totals(spans, base, pf)
             js = eng.__dict__.get("jax_stats")
             if js is not None and stats["chunks"]:
                 js.update(stats["spans"])
         self.stream_stats = stats
         return self.result(arena.finish())
 
-    def _span_totals(self, spans: Spans, base: dict) -> dict:
+    def _span_totals(self, spans: Spans, base: dict,
+                     pf: _Prefetcher | None) -> dict:
         """The stream's span seconds and counters so far: flat, monotone
         totals (counters as differences from the stream's start)."""
         out = dict(spans.totals)
+        out["late_pulls"] = pf.late_pulls if pf is not None else 0
         core = _engine_core(self.engine)
         for k in ("d2h_reads", "resident_regrows"):
             out[k] = (getattr(core, k) if core is not None else 0) \

@@ -19,7 +19,12 @@ before it, so between two twin calls every second lands in exactly one of
 Two more are measured off the consumer thread and added when the loop takes
 the chunk: ``stage`` (the transfer thread's ``stage_chunk``: task arrays,
 padding, ``device_put``) and ``ready_wait`` (from the staged chunk being
-ready to the loop taking it: the prefetch queue).
+ready to the loop taking it: the prefetch queue). After a full chunk the
+next pull starts as the loop takes it, and ``ready_wait`` is what is left of
+that chunk's cycle once the next one is staged. After a short chunk (a
+caught-up source, which has nothing more to give until time passes) the
+pull waits for the chunk's backend call, so ``ready_wait`` is only what is
+left of the backend call and the ``tail`` once staging is done.
 
 Every consumer span, and ``stage`` on the transfer thread, is also a
 ``jax.profiler.TraceAnnotation(<span>, chunk=<sequence number>)``, so a
