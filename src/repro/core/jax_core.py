@@ -61,8 +61,11 @@ bits): predicted latencies and costs, the surplus bank, and times. Times
 are also rebased per chunk: the device holds ``t - base`` with ``base`` the
 chunk's first arrival, kept exactly on the host, so no absolute time of a
 long stream (1e8+ ms) reaches the device. Costs are gathered from per-config
-tables the host computes with the oracle's own float64 formula. Which branch
-runs is decided by ``platform()``, the core's one read of the device.
+tables the host computes with the oracle's own float64 formula, on both
+branches, beside each cost's rank among them (``cost_ranks``): Lambda billing
+makes exact ties in real arithmetic that float64 breaks by one ulp, which
+two-float cannot resolve, so one cost is compared with another by rank. Which
+branch runs is decided by ``platform()``, the core's one read of the device.
 
 Parity contract (mirrors the Pallas kernel tests):
 
@@ -123,6 +126,12 @@ SCAN_MODE = "auto"
 _AUTO_SCAN = {"cpu": "seq"}
 
 POOL_MIN_CAP = 8        # starting CIL container-pool capacity (doubles on demand)
+# ... and at least one slot per this many padded chunk rows: a chunk's cold
+# starts take slots before the reap that frees them, so the width a stream
+# needs grows with its chunks. FD MinCost's 16,384-row chunks need up to 128
+# over a simulated day; starting there keeps the regrow (a new place program)
+# out of a long stream.
+POOL_ROWS_PER_SLOT = 128
 PAD_MIN = 8             # minimum padded chunk rows
 PLACE_BLOCK = 32        # rows per compiled fixed point (``_build_place``)
 # the place step's per-chunk scalars (every other input is per row), and
@@ -209,6 +218,9 @@ class _F64:
     def reduce_min(x, axis):
         return x.min(axis=axis)
 
+    def round(self, x):
+        return self.jnp.round(x)
+
     def argmin(self, x, axis):
         return self.jnp.argmin(x, axis=axis)
 
@@ -249,6 +261,7 @@ class _DF32:
     maximum = staticmethod(dfloat.maximum)
     where = staticmethod(dfloat.where)
     reduce_min = staticmethod(dfloat.reduce_min)
+    round = staticmethod(dfloat.round_half_even)
     argmin = staticmethod(dfloat.argmin)
     argmax = staticmethod(dfloat.argmax)
 
@@ -275,6 +288,14 @@ class _DF32:
     @staticmethod
     def host(v) -> np.ndarray:
         return dfloat.join(*v)
+
+
+def cost_ranks(cost) -> np.ndarray:
+    """Dense int32 rank of each float64 cost among all of them and the free
+    edge's 0.0 (which ranks 0): equal ranks iff equal costs, and the ranks
+    in the order of the costs."""
+    _, inv = np.unique(np.append(np.ravel(cost), 0.0), return_inverse=True)
+    return inv[:-1].reshape(np.shape(cost)).astype(np.int32)
 
 
 def _next_pow2(n: int) -> int:
@@ -461,8 +482,8 @@ class JaxPlacementCore:
         tpu = plat == "tpu"
         # the TPU has no float64: decide in two-float there (module docstring)
         self.A = _DF32(self.jnp, self.lax) if tpu else _F64(self.jnp)
-        if self.A.df and any(not c.quantum.is_integer() for c in self.cloud):
-            raise CoreIneligible("two-float billing needs integral quanta")
+        if any(not c.quantum.is_integer() for c in self.cloud):
+            raise CoreIneligible("billing needs integral quanta")
         self.use_gbrt_kernel = bool(self.n_cloud) and (
             mode == "force" or (tpu and mode == "auto"))
         # interpret mode follows the device the kernel really runs on: off on
@@ -509,6 +530,7 @@ class JaxPlacementCore:
         self.chunk_commits = 0    # legacy per-chunk host commits
         self.resident_regrows = 0  # donated-seed restore+retry events
         self.d2h_reads = 0        # device arrays place_chunk read back
+        self.cost_rank_splits = 0  # rows whose cost tie a rank split
 
     # ------------------------------------------------------------ lifecycle
     def _scope(self):
@@ -570,12 +592,7 @@ class JaxPlacementCore:
                 t[k] = dev(np.array([getattr(c, attr) for c in self.cloud]))
             t["UP0"] = dev(np.array([c.up_theta[0] for c in self.cloud]))
             t["UP1"] = dev(np.array([c.up_theta[1] for c in self.cloud]))
-            if self.A.df:
-                t["QI"], t["COSTK"] = self._cost_tables(VL)
-            else:
-                t["QNT"] = dev(np.array([c.quantum for c in self.cloud]))
-                t["GB"] = dev(np.array([c.gb for c in self.cloud]))
-                t["RATE"] = dev(np.array([c.rate for c in self.cloud]))
+            t["QI"], t["BILL"] = self._billing_tables(VL)
         if self.has_edge:
             t["ET0"] = dev(np.array([e.theta[0] for e in self.edges]))
             t["ET1"] = dev(np.array([e.theta[1] for e in self.edges]))
@@ -584,19 +601,28 @@ class JaxPlacementCore:
             t["EST"] = dev(np.array([e.store for e in self.edges]))
         return t
 
-    def _cost_tables(self, VL):
-        """Two-float billing: cost by billed-quantum count ``k``, computed
-        on the host with ``LambdaPricing.cost_batch``'s float64 formula —
-        ``((k*q / 1000) * gb) * rate`` — so a cost on the device is the
-        oracle's cost, split. Compute times are bounded by the serving step
-        tables (the GBRT's range), which bounds ``k``."""
+    def _billing_tables(self, VL):
+        """Billing by billed-quantum count ``k``: the int32 quanta, and one
+        stacked table ``BILL[:, config, k]`` of the cost (one float64 row,
+        or the two rows of its two-float split) over its ``cost_ranks``
+        rank, held in the same float type (exact below 2**24 distinct costs;
+        a 15-minute Lambda limit bills 9,000 quanta per config). Costs are
+        computed on the host with ``LambdaPricing.cost_batch``'s float64
+        formula, ``((k*q / 1000) * gb) * rate``, so a cost on the device is
+        the oracle's cost, and the rank orders costs as the oracle does.
+        Compute times are bounded by the serving step tables (the GBRT's
+        range), which bounds ``k``."""
         q = np.array([c.quantum for c in self.cloud])
         top = np.maximum(np.round(np.abs(VL).max(axis=1)), 1.0)
         K = int(np.ceil(top / q).max()) + 2
         k = np.arange(K, dtype=np.float64)
         cost = np.stack([((k * c.quantum / 1000.0) * c.gb) * c.rate
                          for c in self.cloud])
-        return self.jnp.asarray(q.astype(np.int32)), self.A.dev(cost)
+        rows = list(dfloat.split(cost)) if self.A.df else [cost]
+        bill = np.stack(rows + [cost_ranks(cost)]).astype(
+            np.float32 if self.A.df else np.float64)
+        return (self.jnp.asarray(q.astype(np.int32)),
+                self.jnp.asarray(bill))
 
     def _gbrt_kernel_operands(self):
         """Stacked multi-config Pallas operands for the ONE blocked
@@ -654,20 +680,15 @@ class JaxPlacementCore:
             cfg = jnp.arange(nc)[None, :]
             return jax.tree.map(lambda v: v[cfg, k], t["VL"])
 
-        def billed_cost(compc):
-            if not A.df:
-                billed = jnp.ceil(
-                    jnp.maximum(jnp.round(compc), 1.0) / t["QNT"][None, :]
-                ) * t["QNT"][None, :]
-                return ((billed / 1000.0) * t["GB"][None, :]) \
-                    * t["RATE"][None, :]
-            # np.round, then ceil to the quantum, in exact integers
-            m = jnp.maximum(dfloat.round_half_even(compc), 1.0)
+        def billed(compc):
+            # np.round, then up to the quantum, in exact integers; then one
+            # gather of the host's (cost, rank) table
+            m = jnp.maximum(A.round(compc), 1.0).astype(jnp.int32)
             q = t["QI"][None, :]
-            k = (m.astype(jnp.int32) + q - 1) // q
-            k = jnp.clip(k, 0, t["COSTK"][0].shape[1] - 1)
-            cfg = jnp.arange(nc)[None, :]
-            return jax.tree.map(lambda v: v[cfg, k], t["COSTK"])
+            k = jnp.clip((m + q - 1) // q, 0, t["BILL"].shape[2] - 1)
+            g = t["BILL"][:, jnp.arange(nc)[None, :], k]
+            cost = (g[0], g[1]) if A.df else g[0]
+            return cost, g[-1].astype(jnp.int32)
 
         def predict(sizes, nbytes):
             out = {}
@@ -686,7 +707,7 @@ class JaxPlacementCore:
                 out["OCCW"] = occ_w
                 out["OCCC"] = occ_c
                 out["COMPC"] = compc
-                out["COSTC"] = billed_cost(compc)
+                out["COSTC"], out["RANKC"] = billed(compc)
             if nd:
                 ec = A.maximum(
                     A.mul(A.add(row(t["ET0"]),
@@ -834,11 +855,12 @@ class JaxPlacementCore:
                 COLD = jnp.zeros((R, 0), dtype=bool)
 
             # --- (R, T) policy-view matrices -------------------------------
-            cols_lat, cols_cost, cols_comp = [], [], []
+            cols_lat, cols_cost, cols_comp, cols_rank = [], [], [], []
             if nc:
                 cols_lat.append(A.where(COLD, P["LATC"], P["LATW"]))
                 cols_cost.append(P["COSTC"])
                 cols_comp.append(P["COMPC"])
+                cols_rank.append(P["RANKC"])
             if has_edge:
                 def pick(x):
                     return tm(lambda v: v[rr, nom][:, None], x)
@@ -848,11 +870,13 @@ class JaxPlacementCore:
                         ew, tm(lambda v: v[rr, nom], P["ELAT"]))))
                 cols_cost.append(pick(P["ECOST"]))
                 cols_comp.append(pick(P["ECOMP"]))
+                cols_rank.append(jnp.zeros((R, 1), jnp.int32))  # free
 
             def cat(cols):
                 return tm(lambda *v: jnp.concatenate(v, axis=1), *cols)
 
             LAT, COST, COMP = cat(cols_lat), cat(cols_cost), cat(cols_comp)
+            RANK = jnp.concatenate(cols_rank, axis=1)
 
             # --- surplus bank (the third recurrence; MinLatency only) ------
             s_before = s_fin = None
@@ -874,9 +898,10 @@ class JaxPlacementCore:
                         incl, A.full(1, 0.0)))
                     s_fin = A.add(P["s0"], tm(lambda v: v[-1], incl))
             return {"nom": nom, "ew": ew, "LAT": LAT, "COST": COST,
-                    "COMP": COMP, "COLD": COLD, "s_before": s_before,
-                    "s_fin": s_fin, "h_fin": h_fin, "busyF": busyF,
-                    "lastF": lastF, "cntF": cntF, "overflow": overflow}
+                    "RANK": RANK, "COMP": COMP, "COLD": COLD,
+                    "s_before": s_before, "s_fin": s_fin, "h_fin": h_fin,
+                    "busyF": busyF, "lastF": lastF, "cntF": cntF,
+                    "overflow": overflow}
 
         return state_fn
 
@@ -888,7 +913,20 @@ class JaxPlacementCore:
         def col(x):
             return jax.tree.map(lambda v: v[:, None], x)
 
-        def choose_fn(LAT, COST, allowed, deadline, valid):
+        def cheapest(keep, COST, RANK):
+            """The ``keep`` columns of least cost, by rank (float64's order
+            of the costs), and the rows where the arithmetic's own cost
+            compare tied a column the rank left out."""
+            r = jnp.where(keep, RANK, jnp.iinfo(jnp.int32).max)
+            final = keep & (RANK == r.min(axis=1)[:, None])
+            cmin = A.reduce_min(A.where(keep, COST, A.inf), 1)
+            split = (keep & ~final & A.eq(COST, col(cmin))).any(axis=1)
+            return final, split
+
+        def choose_fn(LAT, COST, RANK, allowed, deadline, valid):
+            """Codes, feasibility, and the rows whose least cost the rank
+            split (``cost_rank_splits``); only costs against the budget
+            compare in the arithmetic."""
             R = valid.shape[0]
             if is_minlat:
                 feas = A.le(COST, col(allowed))
@@ -901,24 +939,20 @@ class JaxPlacementCore:
                 l1 = A.where(feas, LAT, A.inf)
                 lmin = A.reduce_min(l1, 1)
                 tie = feas & A.eq(LAT, col(lmin))
-                c2 = A.where(tie, COST, A.inf)
-                cmin = A.reduce_min(c2, 1)
-                final = tie & A.eq(COST, col(cmin))
+                final, split = cheapest(tie, COST, RANK)
                 code = final.argmax(axis=1).astype(jnp.int32)
                 feas_out = jnp.ones(R, dtype=bool)
             else:  # MinCostPolicy (edge column guaranteed by eligibility)
                 feas = A.le(LAT, deadline)
                 any_f = feas.any(axis=1)
-                c1 = A.where(feas, COST, A.inf)
-                cmin = A.reduce_min(c1, 1)
-                tie = feas & A.eq(COST, col(cmin))
+                tie, split = cheapest(feas, COST, RANK)
                 l2 = A.where(tie, LAT, A.inf)
                 lmin = A.reduce_min(l2, 1)
                 final = tie & A.eq(LAT, col(lmin))
                 code = final.argmax(axis=1).astype(jnp.int32)
                 code = jnp.where(any_f, code, edge_col)
                 feas_out = any_f
-            return jnp.where(valid, code, -1), feas_out
+            return jnp.where(valid, code, -1), feas_out, split & valid
 
         return choose_fn
 
@@ -1030,9 +1064,9 @@ class JaxPlacementCore:
                                                   st["s_before"]))
             else:
                 allowed = A.full(guess.shape[0], np.inf)
-            code, feas = choose_fn(st["LAT"], st["COST"], allowed,
-                                   P["deadline"], P["valid"])
-            return st, code, feas, allowed
+            code, feas, split = choose_fn(st["LAT"], st["COST"], st["RANK"],
+                                          allowed, P["deadline"], P["valid"])
+            return st, code, feas, allowed, split
 
         def fixed_point(P):
             R = P["valid"].shape[0]
@@ -1048,9 +1082,10 @@ class JaxPlacementCore:
                 return g, step(g, P)[1], i + 1
 
             _, gF, iters = lax.while_loop(cond, body, (g0, g1, jnp.int32(1)))
-            st, code, feas, allowed = step(gF, P)  # fixed point: code == gF
+            st, code, feas, allowed, split = step(gF, P)  # code == gF
             res = finalize(st, code, feas, allowed, P)
             res["iters"] = iters
+            res["splits"] = split.sum(dtype=jnp.int32)
             res["converged"] = ~jnp.any(code != gF)
             return res
 
@@ -1088,9 +1123,11 @@ class JaxPlacementCore:
             res = {k: jax.tree.map(
                 lambda v: v.reshape((R,) + v.shape[2:]), v)
                 for k, v in out.items()
-                if k not in ("overflow", "iters", "converged")}
+                if k not in ("overflow", "iters", "splits", "converged")}
             res["overflow"] = out["overflow"].any()
-            res["iters"] = out["iters"].sum()
+            # one read for both counts: passes, rows the rank split
+            res["counts"] = jnp.stack([out["iters"].sum(dtype=jnp.int32),
+                                       out["splits"].sum(dtype=jnp.int32)])
             res["converged"] = out["converged"].all()
             for seed, final in _STATE_OUTPUTS:
                 if seed in S:
@@ -1107,7 +1144,7 @@ class JaxPlacementCore:
         jax, jnp, A = self.jax, self.jnp, self.A
         g = jnp.asarray(np.full(R, -1, np.int32))
         g_np = np.asarray(g)
-        st = code = feas = allowed = None
+        st = code = feas = allowed = split = None
         iters = 0
         converged = False
         for _ in range(R + 2):
@@ -1118,8 +1155,9 @@ class JaxPlacementCore:
                                     A.mul(P["alpha"], st["s_before"]))
             else:
                 allowed = A.full(R, np.inf)
-            code, feas = self._choose(st["LAT"], st["COST"], allowed,
-                                      P["deadline"], P["valid"])
+            code, feas, split = self._choose(st["LAT"], st["COST"],
+                                             st["RANK"], allowed,
+                                             P["deadline"], P["valid"])
             iters += 1
             c_np = np.asarray(code)
             if np.array_equal(c_np, g_np):
@@ -1129,7 +1167,7 @@ class JaxPlacementCore:
         res = dict(self._finalize(st, code, feas, allowed, P))
         # the converging (verification) pass isn't an iteration, matching the
         # compiled driver's count
-        res["iters"] = max(iters - 1, 1)
+        res["counts"] = np.array([max(iters - 1, 1), int(split.sum())])
         res["converged"] = converged
         return res
 
@@ -1334,6 +1372,7 @@ class JaxPlacementCore:
         else:
             max_existing = max((len(p) for p in pools), default=0)
             cap = _next_pow2(max(self._cap_hint, POOL_MIN_CAP))
+        cap = max(cap, R // POOL_ROWS_PER_SLOT)
 
         with self._scope():
             if rs is not None:
@@ -1431,7 +1470,8 @@ class JaxPlacementCore:
                    ("lat", "cost", "comp", "wait", "allowed")}
             out.update({k: self._read(res[k])[:n] for k in
                         ("gcode", "cold", "feas")})
-            iters = int(self._read(res["iters"]))
+            iters, splits = (int(v) for v in self._read(res["counts"]))
+            self.cost_rank_splits += splits
             t_last = float(nows_np[-1])
             if residency:
                 # ---- stay resident: committed state LIVES on device -------

@@ -924,7 +924,8 @@ def _no_lap(name: str) -> None:
 
 # the core's monotone counters a stream reports as its own differences
 _CORE_COUNTERS = ("state_syncs", "fallback_syncs", "resident_chunks",
-                  "chunk_commits", "resident_regrows", "d2h_reads")
+                  "chunk_commits", "resident_regrows", "d2h_reads",
+                  "cost_rank_splits")
 
 
 def _engine_core(eng):
@@ -1197,10 +1198,11 @@ class PlacementRuntime:
         numpy served.
 
         A jax-backed stream also records per-chunk spans of its loop
-        (``repro.core.spans``) and four counters: ``d2h_reads`` (device
+        (``repro.core.spans``) and five counters: ``d2h_reads`` (device
         arrays the core read back), ``twin_slots`` (container slots the
-        twin's pool walk visited), ``resident_regrows`` and
-        ``late_pulls``. Their stream
+        twin's pool walk visited), ``resident_regrows``, ``late_pulls``
+        and ``cost_rank_splits`` (rows whose least cost the core's cost
+        ranks split where its arithmetic saw a tie). Their stream
         totals join ``engine.jax_stats`` before each backend call of a
         chunk the core placed (so the difference of two consecutive
         readings is one loop cycle's), and land in ``stream_stats["spans"]``
@@ -1345,7 +1347,7 @@ class PlacementRuntime:
         out = dict(spans.totals)
         out["late_pulls"] = pf.late_pulls if pf is not None else 0
         core = _engine_core(self.engine)
-        for k in ("d2h_reads", "resident_regrows"):
+        for k in ("d2h_reads", "resident_regrows", "cost_rank_splits"):
             out[k] = (getattr(core, k) if core is not None else 0) \
                 - base.get(k, 0)
         out["twin_slots"] = getattr(self.backend, "twin_slots", 0) \

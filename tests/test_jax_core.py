@@ -166,6 +166,141 @@ def test_compiled_decision_equality(ir_setup, policy_fn, policy_name):
     assert not rt.engine.jax_stats["interpret"]
 
 
+# ------------------------------------------------- the billing order
+DAY_MS = 86_400_000.0
+BRANCHES = ["float64", "two_float"]
+
+
+def _steer(monkeypatch, branch):
+    """On "two_float" the TPU branch runs on this CPU host (two-float
+    arithmetic, the GBRT kernel interpreted)."""
+    if branch == "two_float":
+        monkeypatch.setattr(jax_core, "platform", lambda: "tpu")
+
+
+def _fd_runtime(twin, models, configs, seed=11):
+    pred = build_fleet_predictor(models, dict(FLEET3), configs=configs)
+    eng = DecisionEngine(predictor=pred,
+                         policy=MinCostPolicy(deadline_ms=4500.0))
+    return PlacementRuntime(eng, TwinBackend(
+        twin, seed=seed, edge_names=tuple(FLEET3), edge_speed=FLEET3))
+
+
+@pytest.fixture(scope="module")
+def fd19_setup():
+    """FD on the paper's 19 Lambda configs, placed by MinCost at the Table
+    III deadline, 2,048 Poisson arrivals a day into the stream."""
+    from repro.core.apps import MEMORY_CONFIGS_MB
+    from repro.core.workload import PoissonWorkload
+
+    twin, models = fit_app("FD", seed=0, n_inputs=200,
+                           configs=MEMORY_CONFIGS_MB)
+    tasks = PoissonWorkload(rate_per_s=4.0, size_sampler=twin.sample_input,
+                            seed=3).generate(2048)
+    for t in tasks:
+        t.arrival_ms += DAY_MS
+    return twin, models, MEMORY_CONFIGS_MB, tasks
+
+
+@pytest.mark.parametrize("branch", BRANCHES)
+def test_compiled_mincost_decides_the_billing_order_as_float64(
+        fd19_setup, monkeypatch, branch):
+    """Lambda billing makes exact ties in real arithmetic that float64
+    splits by one ulp; compiled float64 (reassociated) and two-float (48
+    bits) each lost that order and decided hundreds of FD tasks otherwise.
+    The rank table keeps the oracle's order on both branches."""
+    twin, models, configs, tasks = fd19_setup
+    ref = _fd_runtime(twin, models, configs).serve_stream(tasks,
+                                                          chunk_size=512)
+    _steer(monkeypatch, branch)
+    rt = _fd_runtime(twin, models, configs)
+    res = rt.serve_stream(tasks, chunk_size=512, array_backend="jax")
+    ra, rb = ref.records, res.records
+    assert list(ra.targets) == list(rb.targets)
+    for col in ("predicted_cold", "actual_cold", "feasible"):
+        assert np.array_equal(getattr(ra, col), getattr(rb, col)), col
+    for col in FLOAT_COLS:
+        np.testing.assert_allclose(
+            getattr(ra, col).astype(float), getattr(rb, col).astype(float),
+            rtol=1e-9, atol=1e-12, err_msg=col)
+    assert len(set(ra.targets)) > 3        # the order over many configs
+    assert rt.stream_stats["residency"]["fallback_chunks"] == 0
+    splits = rt.stream_stats["spans"]["cost_rank_splits"]
+    assert (splits > 0) if branch == "two_float" else (splits == 0)
+
+
+def _lambda_cost(memory_mb, quanta, rate=1.66667e-5, quantum=100.0):
+    """``LambdaPricing.cost_batch``'s float64 formula."""
+    return (((quanta * quantum) / 1000.0) * (memory_mb / 1024.0)) * rate
+
+
+def test_cost_ranks_order_costs_exactly_as_float64():
+    from repro.core.apps import MEMORY_CONFIGS_MB
+    from repro.kernels import dfloat
+
+    k = np.arange(80, dtype=np.float64)
+    cost = np.stack([_lambda_cost(m, k) for m in MEMORY_CONFIGS_MB])
+    rank = jax_core.cost_ranks(cost)
+    assert rank.shape == cost.shape and rank.dtype == np.int32
+    c, r = cost.ravel(), rank.ravel()
+    assert np.array_equal(r[:, None] == r[None, :], c[:, None] == c[None, :])
+    assert np.array_equal(r[:, None] < r[None, :], c[:, None] < c[None, :])
+    assert r.min() == 0 and (r[c == 0.0] == 0).all()   # the free edge's 0
+    # 768 MB x 16 quanta = 1,024 MB x 12 in real arithmetic: float64 puts
+    # 1,024 MB one ulp below, two-float holds both equal, the ranks differ
+    i768, i1024 = (MEMORY_CONFIGS_MB.index(m) for m in (768, 1024))
+    a, b = cost[i768, 16], cost[i1024, 12]
+    assert b < a
+    assert [x.tolist() for x in dfloat.split([a])] \
+        == [x.tolist() for x in dfloat.split([b])]
+    assert rank[i1024, 12] < rank[i768, 16]
+    hi, lo = dfloat.split(c)
+    merged = (hi[:, None] == hi[None, :]) & (lo[:, None] == lo[None, :])
+    assert (merged & (r[:, None] != r[None, :])).any()
+
+
+@pytest.mark.parametrize("branch", BRANCHES)
+@pytest.mark.parametrize("tie", [(16, 12), (12, 9)],
+                         ids=["768x16=1024x12", "768x12=1024x9"])
+def test_mincost_picks_float64s_side_of_a_billing_tie(monkeypatch, branch,
+                                                      tie):
+    """Two configs whose bills tie in real arithmetic; the edge is out of
+    the deadline. Float64 picks 1,024 MB in the first tie and 768 MB in the
+    second, where 1,024 MB is the faster: a two-float cost compare sees a
+    tie there and would fall through to latency. Every row is such a tie,
+    and on the two-float branch ``cost_rank_splits`` counts each."""
+    import dataclasses
+
+    from repro.core.perf_models import RidgeModel
+
+    q768, q1024 = tie
+    a, b = _lambda_cost(768, q768), _lambda_cost(1024, q1024)
+    assert a != b
+    cheaper = "768" if a < b else "1024"
+    twin, models = fit_app("FD", seed=0, n_inputs=40, configs=(768, 1024))
+    # compute times inside the billed quanta: 768 MB left of the split,
+    # 1,024 MB right of it
+    comp = GBRT(config=GBRTConfig(n_trees=1, max_depth=1, learning_rate=1.0),
+                features=np.array([[1]], np.int32),
+                thresholds=np.array([[896.0]]),
+                leaves=np.array([[q768 * 100.0 - 50.0,
+                                  q1024 * 100.0 - 50.0]]))
+    models = dataclasses.replace(
+        models, comp_cloud=comp,
+        comp_edge=RidgeModel(theta=np.array([1e6, 0.0])))
+    tasks = _bursty(twin, 64)
+    _steer(monkeypatch, branch)
+    rt = _fd_runtime(twin, models, (768, 1024))
+    res = rt.serve_stream(tasks, chunk_size=32, array_backend="jax")
+    assert rt.engine.jax_stats is not None
+    assert set(res.records.targets) == {cheaper}
+    ref = _fd_runtime(twin, models, (768, 1024)).serve_stream(
+        tasks, chunk_size=32)
+    assert set(ref.records.targets) == {cheaper}
+    splits = rt.stream_stats["spans"]["cost_rank_splits"]
+    assert splits == (len(tasks) if branch == "two_float" else 0)
+
+
 # ------------------------------------------------------- fallback regression
 def test_hedged_policy_falls_back_to_numpy(ir_setup):
     twin, models = ir_setup
@@ -377,6 +512,30 @@ def test_resident_pool_growth_donation_safety(ir_setup):
     assert core.resident_regrows >= 1  # the donated-seed retry path ran
     r = rt.stream_stats["residency"]
     assert r["chunk_commits"] == 0 and r["state_syncs"] == 1
+
+
+def test_pool_width_starts_at_a_slot_per_chunk_rows(ir_setup, monkeypatch):
+    """A stream's pools start at one slot per ``POOL_ROWS_PER_SLOT`` padded
+    chunk rows, not at ``POOL_MIN_CAP``: a stream of large chunks then
+    builds fewer place programs (one per width) on its way to the width its
+    chunks' cold starts need. Decisions stay numpy's."""
+    twin, models = ir_setup
+    tasks = _bursty(twin, 4096)
+    ref = _runtime(twin, models).serve_stream(tasks, chunk_size=2048)
+    floor = 2048 // jax_core.POOL_ROWS_PER_SLOT
+    assert floor == 2 * jax_core.POOL_MIN_CAP
+    runs = []
+    for per_slot in (jax_core.POOL_ROWS_PER_SLOT, 1 << 20):
+        monkeypatch.setattr(jax_core, "POOL_ROWS_PER_SLOT", per_slot)
+        rt = _runtime(twin, models)
+        res = rt.serve_stream(tasks, chunk_size=2048, array_backend="jax")
+        assert list(ref.records.targets) == list(res.records.targets)
+        core = jax_core.core_for(rt.engine)
+        runs.append((core.last_stats["pool_cap"],
+                     core.compile_stats()["place"]))
+    (cap, built), (cap_min, built_min) = runs
+    assert cap == cap_min > floor
+    assert built == built_min - 1     # no 8-slot program
 
 
 def test_resident_state_syncs_for_external_place_many(ir_setup):

@@ -33,7 +33,8 @@ from repro.core.spans import CONSUMER, OFF_LOOP
 from repro.core.workload import TaskInput
 from test_jax_core import CONFIGS, _bursty, _runtime
 
-COUNTERS = ("d2h_reads", "twin_slots", "resident_regrows", "late_pulls")
+COUNTERS = ("d2h_reads", "twin_slots", "resident_regrows", "late_pulls",
+            "cost_rank_splits")
 FIELDS = CONSUMER + OFF_LOOP + COUNTERS
 CHUNK = 32
 

@@ -118,6 +118,7 @@ def test_core_place_step_compiles_on_tpu_branch(one_chip, monkeypatch):
 
     P = {k: pair((ROWS, C)) for k in
          ("LATW", "LATC", "OCCW", "OCCC", "COMPC", "COSTC")}
+    P["RANKC"] = spec((ROWS, C), jnp.int32)
     P.update({k: pair((ROWS, nd)) for k in ("ECOMP", "ELAT", "ECOST")})
     P.update(nows=pair((ROWS,)), valid=spec((ROWS,), jnp.bool_),
              nom_fixed=spec((ROWS,), jnp.int32), c_max=pair(()),
