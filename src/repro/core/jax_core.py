@@ -28,8 +28,11 @@ boundaries stop being host↔device sync points):
    pools. By the same induction the numpy repair loop relies on, the exact
    prefix grows by ≥ 1 row per iteration, so the fixed point
    (``pass(g) == g``) IS the true sequential trajectory and is reached in
-   ≤ R+1 passes. A chunk runs as ``PLACE_BLOCK``-row blocks in order (see
-   ``_build_place``): where decisions feed back, a pass repairs few rows.
+   ≤ R+1 passes. The loop carries each pass's state beside its codes, so the
+   converging pass's state is the one committed: a block costs its passes
+   in state replays, and none more. A chunk runs as ``PLACE_BLOCK``-row
+   blocks in order (see ``_build_place``): where decisions feed back, a pass
+   repairs few rows.
 3. **Commit or stay resident** — decision outputs are sliced to the chunk on
    host either way. Without stream residency (standalone ``place_many``),
    CIL pools, edge horizons and the surplus bank are written back exactly
@@ -1069,24 +1072,27 @@ class JaxPlacementCore:
             return st, code, feas, allowed, split
 
         def fixed_point(P):
+            # The loop carries a guess and the whole pass under it, so the
+            # pass that reproduces its guess is the state committed: a block
+            # runs exactly ``iters`` state replays. At most R+3 run, past
+            # the R+1 the fixed point needs.
             R = P["valid"].shape[0]
             g0 = jnp.full(R, -1, dtype=jnp.int32)
-            g1 = step(g0, P)[1]
 
             def cond(c):
-                gp, g, i = c
-                return jnp.any(gp != g) & (i < R + 2)
+                g, out, i = c
+                return jnp.any(out[1] != g) & (i < R + 3)
 
             def body(c):
-                _, g, i = c
-                return g, step(g, P)[1], i + 1
+                _, out, i = c
+                return out[1], step(out[1], P), i + 1
 
-            _, gF, iters = lax.while_loop(cond, body, (g0, g1, jnp.int32(1)))
-            st, code, feas, allowed, split = step(gF, P)  # code == gF
+            g, (st, code, feas, allowed, split), iters = lax.while_loop(
+                cond, body, (g0, step(g0, P), jnp.int32(1)))
             res = finalize(st, code, feas, allowed, P)
             res["iters"] = iters
             res["splits"] = split.sum(dtype=jnp.int32)
-            res["converged"] = ~jnp.any(code != gF)
+            res["converged"] = ~jnp.any(code != g)
             return res
 
         def place(P, S):
@@ -1165,8 +1171,9 @@ class JaxPlacementCore:
                 break
             g, g_np = code, c_np
         res = dict(self._finalize(st, code, feas, allowed, P))
-        # the converging (verification) pass isn't an iteration, matching the
-        # compiled driver's count
+        # one fixed point over the whole chunk, counted without its
+        # converging pass (at least 1); the compiled fixed point counts every
+        # pass of every block, that one included
         res["counts"] = np.array([max(iters - 1, 1), int(split.sum())])
         res["converged"] = converged
         return res
@@ -1509,6 +1516,10 @@ class JaxPlacementCore:
         nom_out = None
         if self.has_edge:
             nom_out = self._read(res["nom"])[:n].astype(np.int64)
+        # ``iters``: the compiled fixed point's state replays summed over the
+        # chunk's blocks (the interpreted one's, less its converging pass).
+        # ``passes`` is that plus one and ``repairs`` that less one: fixed
+        # offsets, so ``passes`` per block stays comparable across versions.
         engine.columnar_stats = {"chunks": 1, "repairs": max(iters - 1, 0),
                                  "walked": 0, "n": n}
         self.last_stats = {"n": n, "passes": iters + 1, "rows": R,

@@ -6,7 +6,8 @@ bytes, and optionally the latency that was observed when the arrival was
 originally served. Two interchangeable encodings carry the same schema:
 
 - **JSONL** (``.jsonl``): a header line ``{"schema": "repro.trace",
-  "version": 1, "apps": [...], "n": ...}`` followed by one record per line —
+  "version": 1, "apps": [...], "n": ...}`` (with ``"lat": true`` when the
+  trace carries observed latency) followed by one record per line —
   human-greppable, appendable, diff-able. Floats are written with Python's
   shortest round-tripping ``repr``, so a JSONL round trip is BIT-EXACT.
 - **NPZ** (``.npz``): the columns saved directly — the fast path for large
@@ -251,6 +252,8 @@ class Trace:
         if self.meta:
             header["meta"] = self.meta
         lat = self.observed_latency_ms
+        if lat is not None:
+            header["lat"] = True    # the column of an empty trace too
         with open(path, "w") as f:
             f.write(json.dumps(header) + "\n")
             for i in range(self.n):
@@ -351,7 +354,8 @@ def load_jsonl(path, validate: bool = True) -> Trace:
         bytes=np.array(nbytes, dtype=np.float64),
         app_codes=np.array(codes, dtype=np.int64),
         app_names=tuple(str(a) for a in apps),
-        observed_latency_ms=np.array(lats, dtype=np.float64) if lats else None,
+        observed_latency_ms=np.array(lats, dtype=np.float64)
+        if lats or header.get("lat") else None,
         meta=dict(header.get("meta") or {}),
         version=version,
     )

@@ -166,7 +166,72 @@ def test_compiled_decision_equality(ir_setup, policy_fn, policy_name):
     assert not rt.engine.jax_stats["interpret"]
 
 
-# ------------------------------------------------- the billing order
+# ------------------------------------------------- the place fixed point
+@pytest.mark.parametrize("policy_name,policy_fn", _policies())
+def test_place_program_replays_the_state_once_per_pass(
+        ir_setup, monkeypatch, policy_name, policy_fn):
+    """A traced fixed point calls the state replay twice: the first pass
+    before the while loop and the pass in its body. The loop carries the
+    last pass's state out to ``finalize``, so no pass runs again after it
+    and a block costs exactly its ``passes`` state replays."""
+    calls = {"_build_state": 0, "_build_finalize": 0}
+
+    def counted(name):
+        build = getattr(jax_core.JaxPlacementCore, name)
+
+        def wrap(self):
+            fn = build(self)
+
+            def f(*args):
+                calls[name] += 1
+                return fn(*args)
+
+            return f
+
+        return wrap
+
+    for name in calls:
+        monkeypatch.setattr(jax_core.JaxPlacementCore, name, counted(name))
+    twin, models = ir_setup
+    rt = _runtime(twin, models, policy=policy_fn())
+    rt.serve_stream(_bursty(twin, 100), chunk_size=64, array_backend="jax")
+    assert not rt.engine.jax_stats["interpret"]
+    # one finalize per traced fixed point (one per place program traced)
+    assert calls["_build_finalize"] >= 1
+    assert calls["_build_state"] == 2 * calls["_build_finalize"]
+
+
+def _record_place(monkeypatch):
+    """Per call of the compiled place program: its passes summed over the
+    blocks, the rows the rank split, whether every block converged and
+    whether a pool overflowed (the call is then made again); per chunk the
+    core placed, ``jax_stats["passes"]``."""
+    calls, passes = [], []
+    orig = jax_core.JaxPlacementCore.place_chunk
+
+    def place_chunk(self, engine, *args, **kw):
+        place = self._place
+
+        def rec(P, S):
+            res = place(P, S)
+            calls.append((*np.asarray(res["counts"]).tolist(),
+                          bool(res["converged"]), bool(res["overflow"])))
+            return res
+
+        self._place = rec
+        try:
+            out = orig(self, engine, *args, **kw)
+        finally:
+            self._place = place
+        if out is not None:
+            passes.append(engine.jax_stats["passes"])
+        return out
+
+    monkeypatch.setattr(jax_core.JaxPlacementCore, "place_chunk",
+                        place_chunk)
+    return calls, passes
+
+
 DAY_MS = 86_400_000.0
 BRANCHES = ["float64", "two_float"]
 
@@ -178,6 +243,49 @@ def _steer(monkeypatch, branch):
         monkeypatch.setattr(jax_core, "platform", lambda: "tpu")
 
 
+# The fixed point's trajectory on fixed-seed streams, per branch: per place
+# call (passes summed over its blocks, rank splits, converged, overflow), per
+# chunk ``jax_stats["passes"]``. How the loop carries a pass's state must
+# not move them: a change here is a change of the fixed point itself.
+_IR_CALLS = [(17, 0, True, True), (17, 0, True, True), (16, 0, True, False),
+             (14, 0, True, True), (14, 0, True, True), (14, 0, True, False),
+             (8, 0, True, False), (2, 0, True, False)]
+IR_TRAJECTORY = {"float64": (_IR_CALLS, [17, 15, 9, 3]),
+                 "two_float": (_IR_CALLS, [17, 15, 9, 3])}
+FD_TRAJECTORY = {
+    "float64": ([(36, 0, True, True), (36, 0, True, False),
+                 (35, 0, True, False), (36, 0, True, False),
+                 (32, 0, True, False)], [37, 36, 37, 33]),
+    "two_float": ([(36, 43, True, True), (36, 43, True, False),
+                   (35, 41, True, False), (36, 39, True, False),
+                   (32, 50, True, False)], [37, 36, 37, 33])}
+
+
+@pytest.mark.parametrize("branch", BRANCHES)
+def test_compiled_fixed_point_trajectory_on_an_ir_stream(
+        ir_setup, monkeypatch, branch):
+    """IR under MinLatency with a live surplus bank and the LPW edge scan,
+    128-row chunks of four blocks: the compiled fixed point takes the pinned
+    passes on both branches, and decides as the numpy oracle does."""
+    twin, models = ir_setup
+    tasks = _bursty(twin, 400)
+    ref = _runtime(twin, models).serve_stream(tasks, chunk_size=128)
+    _steer(monkeypatch, branch)
+    calls, passes = _record_place(monkeypatch)
+    rt = _runtime(twin, models)
+    res = rt.serve_stream(tasks, chunk_size=128, array_backend="jax")
+    assert (calls, passes) == IR_TRAJECTORY[branch]
+    ra, rb = ref.records, res.records
+    assert list(ra.targets) == list(rb.targets)
+    for col in ("predicted_cold", "actual_cold", "feasible"):
+        assert np.array_equal(getattr(ra, col), getattr(rb, col)), col
+    for col in FLOAT_COLS:
+        np.testing.assert_allclose(
+            getattr(ra, col).astype(float), getattr(rb, col).astype(float),
+            rtol=1e-9, atol=1e-12, err_msg=col)
+
+
+# ------------------------------------------------- the billing order
 def _fd_runtime(twin, models, configs, seed=11):
     pred = build_fleet_predictor(models, dict(FLEET3), configs=configs)
     eng = DecisionEngine(predictor=pred,
@@ -208,13 +316,16 @@ def test_compiled_mincost_decides_the_billing_order_as_float64(
     """Lambda billing makes exact ties in real arithmetic that float64
     splits by one ulp; compiled float64 (reassociated) and two-float (48
     bits) each lost that order and decided hundreds of FD tasks otherwise.
-    The rank table keeps the oracle's order on both branches."""
+    The rank table keeps the oracle's order on both branches, and the fixed
+    point over its 16-block chunks takes the pinned passes."""
     twin, models, configs, tasks = fd19_setup
     ref = _fd_runtime(twin, models, configs).serve_stream(tasks,
                                                           chunk_size=512)
     _steer(monkeypatch, branch)
+    calls, passes = _record_place(monkeypatch)
     rt = _fd_runtime(twin, models, configs)
     res = rt.serve_stream(tasks, chunk_size=512, array_backend="jax")
+    assert (calls, passes) == FD_TRAJECTORY[branch]
     ra, rb = ref.records, res.records
     assert list(ra.targets) == list(rb.targets)
     for col in ("predicted_cold", "actual_cold", "feasible"):
